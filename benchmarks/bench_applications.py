@@ -24,6 +24,7 @@ import time
 
 import numpy as np
 
+from repro.core.compile_cache import configure_compile_cache
 from repro.applications.imputation import ProximityImputer
 from repro.applications.outliers import outlier_scores
 from repro.core.api import ForestKernel
@@ -139,6 +140,7 @@ def main() -> None:
     ap.add_argument("--dense-cap-gb", type=float, default=4.0)
     ap.add_argument("--out", default="BENCH_applications.json")
     args = ap.parse_args()
+    configure_compile_cache()
     run(n=args.n, d=args.d, trees=args.trees, repeats=args.repeats,
         grid=tuple(int(g) for g in args.grid.split(",")),
         impute_iters=args.impute_iters, dense_cap_gb=args.dense_cap_gb,

@@ -28,6 +28,7 @@ import time
 
 import numpy as np
 
+from repro.core.compile_cache import configure_compile_cache
 from repro.data.synthetic import gaussian_classes
 from repro.forest.ensemble import RandomForest
 from repro.forest.trees import route_forest_batched, route_forest_numpy
@@ -117,6 +118,7 @@ def main() -> None:
     ap.add_argument("--repeats", type=int, default=3)
     ap.add_argument("--out", default="BENCH_routing.json")
     args = ap.parse_args()
+    configure_compile_cache()
     run(n=args.n, d=args.d, trees=args.trees, out_path=args.out,
         repeats=args.repeats)
 

@@ -28,6 +28,7 @@ from typing import Dict, List
 
 import numpy as np
 
+from repro.core.compile_cache import configure_compile_cache
 from repro.core.api import ForestKernel
 from repro.core.leafmap import sparse_bytes
 from repro.data.synthetic import gaussian_classes
@@ -331,6 +332,7 @@ def _parse_args(argv=None):
 
 def main(argv=None) -> None:
     args = _parse_args(argv)
+    configure_compile_cache()
     if args.out_of_core:
         run_out_of_core(args)
         return

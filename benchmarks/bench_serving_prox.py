@@ -22,6 +22,7 @@ import time
 
 import numpy as np
 
+from repro.core.compile_cache import configure_compile_cache
 from repro.applications.prototypes import compress
 from repro.core.api import ForestKernel
 from repro.data.synthetic import gaussian_classes, train_test_split
@@ -558,6 +559,7 @@ def main() -> None:
     ap.add_argument("--max-obs-inflation", type=float, default=1.05)
     ap.add_argument("--out", default="BENCH_serving_prox.json")
     args = ap.parse_args()
+    configure_compile_cache()
     run(n=args.n, d=args.d, trees=args.trees, backend=args.backend,
         n_prototypes=args.prototypes, proto_k=args.proto_k,
         n_slots=args.slots, n_requests=args.requests,

@@ -33,6 +33,7 @@ import time
 
 import numpy as np
 
+from repro.core.compile_cache import configure_compile_cache
 from repro.data.synthetic import gaussian_classes
 from repro.forest import _native
 from repro.forest.ensemble import RandomForest
@@ -144,5 +145,6 @@ if __name__ == "__main__":
     ap.add_argument("--jax-trees", type=int, default=20)
     ap.add_argument("--out", type=str, default="BENCH_training.json")
     a = ap.parse_args()
+    configure_compile_cache()
     run(n=a.n, d=a.d, trees=a.trees, out_path=a.out, repeats=a.repeats,
         jax_n=a.jax_n, jax_trees=a.jax_trees)

@@ -9,6 +9,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.core.compile_cache import configure_compile_cache
+
 
 def _section(name):
     print(f"\n===== {name} =====", flush=True)
@@ -20,6 +22,7 @@ def main() -> None:
                     help="paper-scale sizes (slow; default is CI-scale)")
     ap.add_argument("--skip", nargs="*", default=[])
     args = ap.parse_args()
+    configure_compile_cache()
     fast = not args.full
 
     t0 = time.time()

@@ -17,7 +17,8 @@ Backends
             so results match scipy to ~1e-12.
 ``pallas``  same segment-sum matvec/matmat, but dense block queries and
             top-k go through the ``block_prox`` Pallas kernel (interpret
-            mode off-TPU).
+            mode on the CPU; compiled, it computes in float32 —
+            ``block_dtype`` says which).
 ``native``  the lazily-compiled C kernels of ``forest._native`` (the same
             ``.so`` as the native router): bucket/gather matmat and dense
             collision blocks, accumulating in float64 like scipy.  Needs a
@@ -64,9 +65,29 @@ class QueryState:
 
 
 def _x64_scope(enabled: bool):
-    from jax.experimental import enable_x64
     import contextlib
-    return enable_x64() if enabled else contextlib.nullcontext()
+
+    import jax
+    return jax.enable_x64(True) if enabled else contextlib.nullcontext()
+
+
+_DEVICE_BACKENDS = ("jax", "pallas")
+
+
+def _count_path(op: str, backend: str, cutover: bool = False) -> None:
+    """Count where an engine op computed its proximities, in the global
+    ``engine_op_path_total{op,backend,path}`` family: ``device`` (jax /
+    pallas arrays), ``host`` (scipy / native) or ``host_cutover`` (a device
+    backend's training-set op above ``_SPARSE_TRAIN_CUTOVER``, run as
+    scipy CSR) — so a device run can tell its device work from host work."""
+    from ..obs.metrics import global_registry
+    on_device = backend in _DEVICE_BACKENDS
+    path = ("host_cutover" if on_device else "host") if cutover else \
+        ("device" if on_device else "host")
+    global_registry().counter(
+        "engine_op_path_total", "engine ops by where they computed",
+        labels=("op", "backend", "path")).labels(
+        op=op, backend=backend, path=path).inc()
 
 
 class ProximityEngine:
@@ -169,6 +190,13 @@ class ProximityEngine:
         self.qs_cache_hits = 0
         self.qs_cache_misses = 0
         self._use_x64 = self.dtype == np.float64
+        # dtype of the dense block ops (kernel_block, topk, OOS squared row
+        # sums) on pallas: the compiled block_prox kernel is float32-only,
+        # so off the CPU the engine asks it for float32 and upcasts the
+        # result to ``dtype``.  Every other op computes in ``dtype``.
+        from ..kernels import interpret_mode
+        self.block_dtype = np.dtype(np.float32) if (
+            self.backend == "pallas" and not interpret_mode()) else self.dtype
         self._train_row_sums: Optional[np.ndarray] = None
         self.last_matmat_path: Optional[str] = None   # 'sharded' | 'segment'
         # reference bucket tables S = Wᵀ V (serving), LRU of key ->
@@ -279,6 +307,7 @@ class ProximityEngine:
     def _dispatch_matmat(self, qs: QueryState, V: np.ndarray,
                          ref_key=None) -> np.ndarray:
         """Backend dispatch for (P V) on an already-resolved query state."""
+        _count_path("matmat", self.backend)
         if self.backend == "scipy":
             return np.asarray(qs.Q @ self._ref_table(V, key=ref_key))
         if self.backend == "native":
@@ -350,27 +379,37 @@ class ProximityEngine:
         with _x64_scope(self._use_x64):
             if qs is self._train_state:
                 mesh = jax_ops.default_mesh()
-                if mesh is not None and n_ref % mesh.devices.shape[0] == 0:
+                if mesh is not None:
                     n_dev = mesh.devices.shape[0]
-                    gl_d, q_d = jnp.asarray(self.gl), jnp.asarray(self.q)
-                    w_d = jnp.asarray(self.w)
+                    # rows padded to a multiple of the device count: leaf 0
+                    # with zero weights and zero V adds nothing, and the
+                    # padded output rows are sliced off
+                    pad = (-n_ref) % n_dev
+                    rows = ((0, pad), (0, 0))
+                    gl_d = jnp.asarray(np.pad(self.gl, rows))
+                    q_d = jnp.asarray(np.pad(self.q, rows))
+                    w_d = jnp.asarray(np.pad(self.w, rows))
                     # wide V: split into column blocks so the per-device
                     # (N/devices, T, c) intermediate stays bounded
-                    c = jax_ops.auto_c_chunk(n_ref // n_dev, T, V.shape[1])
+                    n_loc = (n_ref + pad) // n_dev
+                    c = jax_ops.auto_c_chunk(n_loc, T, V.shape[1])
                     c = V.shape[1] if c is None else c
                     out = np.concatenate([
                         np.asarray(jax_ops.sharded_swlc_matmat(
                             mesh, gl_d, q_d, w_d,
-                            jnp.asarray(V[:, j0:j0 + c]), self.total_leaves))
+                            jnp.asarray(np.pad(V[:, j0:j0 + c], rows)),
+                            self.total_leaves))
                         for j0 in range(0, V.shape[1], c)], axis=1)
                     self.last_matmat_path = "sharded"
-                    return out
-            t_chunk = jax_ops.auto_t_chunk(n_ref, T, V.shape[1])
+                    return out[:n_ref]
+            # one tree per step: leaf ids are tree-major, so each step
+            # scatters N_ref rows into its own tree's buckets.  A single
+            # scatter of all N_ref·T rows costs the TPU compiler minutes.
             out = jax_ops.swlc_predict(jnp.asarray(qs.gl), jnp.asarray(qs.q),
                                        jnp.asarray(self.gl),
                                        jnp.asarray(self.w),
                                        jnp.asarray(V), self.total_leaves,
-                                       t_chunk=t_chunk)
+                                       t_chunk=1)
             self.last_matmat_path = "segment"
             return np.asarray(out)
 
@@ -440,6 +479,7 @@ class ProximityEngine:
         if rows is None:
             rows = np.arange(qs.Q.shape[0])
         rows = np.asarray(rows)
+        _count_path("kernel_block", self.backend)
         if self.backend == "scipy":
             return kernel_block(qs.Q, self.W, rows, cols)
         gl_q, q = qs.gl[rows], qs.q[rows]
@@ -461,8 +501,9 @@ class ProximityEngine:
                         jnp.asarray(q[i0:i0 + step]), gl_w_d, w_d))
             return out
         from ..kernels.block_prox.ops import block_prox
-        with _x64_scope(self._use_x64):
-            return np.asarray(block_prox(gl_q, q, gl_w, w, dtype=self.dtype))
+        with _x64_scope(self.block_dtype == np.float64):
+            out = block_prox(gl_q, q, gl_w, w, dtype=self.block_dtype)
+        return np.asarray(out).astype(self.dtype, copy=False)
 
     def squared_row_sums(self, class_ids: Optional[np.ndarray] = None,
                          n_classes: Optional[int] = None,
@@ -485,8 +526,9 @@ class ProximityEngine:
         else:
             out = np.zeros(n, dtype=self.dtype)
 
-        if self.backend == "scipy" or (
-                X is None and self.W.shape[0] > self._SPARSE_TRAIN_CUTOVER):
+        cutover = X is None and self.W.shape[0] > self._SPARSE_TRAIN_CUTOVER
+        _count_path("squared_row_sums", self.backend, cutover)
+        if self.backend == "scipy" or cutover:
             block = self._budget_block(block)
             WT = self.W.T.tocsc()
             for i0 in range(0, n, block):
@@ -572,8 +614,9 @@ class ProximityEngine:
              block: int = 4096) -> Tuple[np.ndarray, np.ndarray]:
         """Per-query top-k proximities (values descending)."""
         qs = self.query_state(X)
-        if self.backend == "scipy" or (
-                X is None and self.W.shape[0] > self._SPARSE_TRAIN_CUTOVER):
+        cutover = X is None and self.W.shape[0] > self._SPARSE_TRAIN_CUTOVER
+        _count_path("topk", self.backend, cutover)
+        if self.backend == "scipy" or cutover:
             return topk_neighbors(qs.Q, self.W, k,
                                   block=self._budget_block(block))
         n = qs.Q.shape[0]

@@ -23,16 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["swlc_matvec", "swlc_matmat", "swlc_block", "swlc_predict",
-           "swlc_topk", "sharded_swlc_matmat", "default_mesh", "auto_t_chunk"]
-
-
-def _shard_map():
-    """`jax.shard_map` moved out of `jax.experimental` only in newer jax;
-    resolve whichever this jax provides."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-    return shard_map
+           "swlc_topk", "sharded_swlc_matmat", "default_mesh"]
 
 
 def default_mesh(data_axis: str = "data",
@@ -45,15 +36,6 @@ def default_mesh(data_axis: str = "data",
         return None
     return Mesh(np.asarray(devs).reshape(len(devs), 1),
                 (data_axis, model_axis))
-
-
-def auto_t_chunk(n: int, T: int, C: int,
-                 budget_elems: int = 1 << 24) -> Optional[int]:
-    """Tree-chunk size keeping the (n, t_chunk, C) collision intermediate of
-    the segment-sum product under ~budget elements (None = no chunking)."""
-    if n * T * C <= budget_elems:
-        return None
-    return max(1, min(T, budget_elems // max(n * C, 1)))
 
 
 def auto_c_chunk(n_local: int, T: int, C: int,
@@ -85,8 +67,9 @@ def _swlc_product(gl_q: jax.Array, q: jax.Array, gl_w: jax.Array,
 
     ``t_chunk`` bounds the dense collision intermediate: instead of one
     (N, T, C) tensor, both the bucket and gather stages accumulate over tree
-    chunks of size t_chunk, so peak memory is (N, t_chunk, C) — the fix for
-    large C (many classes / wide V).
+    chunks of size t_chunk, so peak memory is (N, t_chunk, C).  It also
+    bounds the rows of each scatter, which is what the TPU compiler's time
+    grows with: the engine passes 1.
     """
     nq, T = gl_q.shape
     nw = gl_w.shape[0]
@@ -135,8 +118,8 @@ def swlc_matmat(gl: jax.Array, q: jax.Array, w: jax.Array, V: jax.Array,
                 t_chunk: Optional[int] = None) -> jax.Array:
     """(P V) for V: (N, C)  — the proximity-weighted prediction primitive.
 
-    Pass ``t_chunk`` (see ``auto_t_chunk``) to cap the dense (N, t_chunk, C)
-    intermediate when C is large.
+    Pass ``t_chunk`` to cap the dense (N, t_chunk, C) intermediate when C
+    is large.
     """
     return _swlc_product(gl, q, gl, w, V, total_leaves, t_chunk)
 
@@ -192,6 +175,35 @@ def swlc_predict(gl_q, q, gl_w, w, Y, total_leaves: int,
     return _swlc_product(gl_q, q, gl_w, w, Y, total_leaves, t_chunk)
 
 
+@functools.partial(jax.jit, static_argnames=("mesh", "total_leaves",
+                                             "data_axis", "model_axis"))
+def _sharded_product(gl, q, w, V, mesh: Mesh, total_leaves: int,
+                     data_axis: str, model_axis: str) -> jax.Array:
+    def local(gl_s, q_s, w_s, V_s):
+        # shapes: gl_s (n/dp, T/mp), V_s (n/dp, C)
+        # local leaf ids are globally unique per model shard -> bucket into a
+        # full-size table to keep indexing static, then psum over data only.
+        # One tree per step, as in _swlc_product (bounded scatter rows).
+        def tree(t):
+            col = functools.partial(jax.lax.dynamic_index_in_dim, index=t,
+                                    axis=1, keepdims=False)
+            return jax.ops.segment_sum(col(w_s)[:, None] * V_s, col(gl_s),
+                                       num_segments=total_leaves)
+
+        # tree 0 seeds the carry, so it has the per-shard type of the body
+        s = jax.lax.fori_loop(1, gl_s.shape[1],
+                              lambda t, s: s + tree(t), tree(0))
+        s = jax.lax.psum(s, data_axis)                     # (L, C)
+        out = (q_s[:, :, None] * s[gl_s]).sum(axis=1)      # (n/dp, C)
+        return jax.lax.psum(out, model_axis)
+
+    spec_nt = P(data_axis, model_axis)
+    spec_nc = P(data_axis, None)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(spec_nt, spec_nt, spec_nt, spec_nc),
+                         out_specs=spec_nc)(gl, q, w, V)
+
+
 def sharded_swlc_matmat(mesh: Mesh, gl: jax.Array, q: jax.Array, w: jax.Array,
                         V: jax.Array, total_leaves: int,
                         data_axis: str = "data",
@@ -202,25 +214,6 @@ def sharded_swlc_matmat(mesh: Mesh, gl: jax.Array, q: jax.Array, w: jax.Array,
     bucket table is psum'ed over `data` and the per-tree partial outputs are
     psum'ed over `model`.
     """
-    n, T = gl.shape
-
-    def local(gl_s, q_s, w_s, V_s):
-        # shapes: gl_s (n/dp, T/mp), V_s (n/dp, C)
-        nl, Tl = gl_s.shape
-        contrib = w_s[:, :, None] * V_s[:, None, :]
-        # local leaf ids are globally unique per model shard -> bucket into a
-        # full-size table to keep indexing static, then psum over data only.
-        s = jax.ops.segment_sum(contrib.reshape(nl * Tl, -1), gl_s.ravel(),
-                                num_segments=total_leaves)
-        s = jax.lax.psum(s, data_axis)                     # (L, C)
-        out = (q_s[:, :, None] * s[gl_s]).sum(axis=1)      # (n/dp, C)
-        return jax.lax.psum(out, model_axis)
-
-    spec_nt = P(data_axis, model_axis)
-    spec_nc = P(data_axis, None)
-    fn = _shard_map()(local, mesh=mesh,
-                      in_specs=(spec_nt, spec_nt, spec_nt, spec_nc),
-                      out_specs=spec_nc)
     # observed into the same engine_op_seconds family the profiled engine
     # wrapper uses, so sharded calls show up in /metrics and snapshots
     # instead of bypassing observability (block_until_ready keeps the
@@ -230,7 +223,8 @@ def sharded_swlc_matmat(mesh: Mesh, gl: jax.Array, q: jax.Array, w: jax.Array,
     from ..obs.metrics import global_registry
     reg = global_registry()
     t0 = _time.perf_counter()
-    out = fn(gl, q, w, V)
+    out = _sharded_product(gl, q, w, V, mesh, total_leaves, data_axis,
+                           model_axis)
     out.block_until_ready()
     dt = _time.perf_counter() - t0
     reg.histogram("engine_op_seconds", "engine op latency (s)",

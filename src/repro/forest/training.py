@@ -78,8 +78,8 @@ _SUB_MAX_PARENTS = 16   # retain parent hists only while a tree's level is
 #                         halved histogram work pays anyway)
 _JAX_TILE = 512         # sample tile per pallas grid step (jax backend)
 _JAX_NODE_CHUNK = 64    # node sub-chunk handed to kernels/histogram/ops
-_JAX_USE_PALLAS = None  # None: pallas iff compiled lowering works, else oracle
-_JAX_INTERPRET = None   # forwarded to ops.resolve_interpret (None = probe)
+_JAX_USE_PALLAS = None  # None: compiled pallas off the CPU, XLA reference on it
+_JAX_INTERPRET = None   # forwarded to ops.resolve_interpret (None = CPU only)
 
 
 @dataclasses.dataclass
@@ -549,6 +549,30 @@ def _best_splits(hist: np.ndarray, msl: float, cls: bool, random_split: bool,
     Float64 throughout; ties broken to the first (lowest-index) maximum —
     both properties shared with the native ``train_best_split`` kernel.
     """
+    gain, node_tot = split_gains(hist, msl, cls)
+    if random_split:
+        # ExtraTrees: one random valid bin per (node, feature).
+        uu = np.where(gain > -np.inf, u, -np.inf)
+        rb = uu.argmax(axis=2)
+        gain = np.take_along_axis(gain, rb[:, :, None], axis=2)[:, :, 0]
+        bins_choice = rb
+    else:
+        bins_choice = gain.argmax(axis=2)
+        gain = np.take_along_axis(gain, bins_choice[:, :, None], axis=2)[:, :, 0]
+
+    if mask is not None:                          # per-node feature subset
+        gain = np.where(mask, gain, -np.inf)
+
+    f_best = gain.argmax(axis=1)
+    g_best = np.take_along_axis(gain, f_best[:, None], axis=1)[:, 0]
+    b_best = np.take_along_axis(bins_choice, f_best[:, None], axis=1)[:, 0]
+    return g_best, f_best, b_best, node_tot
+
+
+def split_gains(hist: np.ndarray, msl: float, cls: bool):
+    """(gain, node_totals): the float64 gain of every (node, feature, bin)
+    split of ``hist`` (nodes, d, bins, C), -inf where a side would hold
+    fewer than ``msl``; the scoring half of ``_best_splits``."""
     cum = np.cumsum(hist, axis=2)                      # left stats at bin b
     tot = cum[:, :, -1:, :]                            # (nodes, d, 1, C)
     R = tot - cum
@@ -571,25 +595,7 @@ def _best_splits(hist: np.ndarray, msl: float, cls: bool, random_split: bool,
 
     valid = (nL >= msl) & (nR >= msl)
     valid[:, :, -1] = False                       # last bin -> empty right side
-    gain = np.where(valid, gain, -np.inf)
-
-    if random_split:
-        # ExtraTrees: one random valid bin per (node, feature).
-        uu = np.where(valid, u, -np.inf)
-        rb = uu.argmax(axis=2)
-        gain = np.take_along_axis(gain, rb[:, :, None], axis=2)[:, :, 0]
-        bins_choice = rb
-    else:
-        bins_choice = gain.argmax(axis=2)
-        gain = np.take_along_axis(gain, bins_choice[:, :, None], axis=2)[:, :, 0]
-
-    if mask is not None:                          # per-node feature subset
-        gain = np.where(mask, gain, -np.inf)
-
-    f_best = gain.argmax(axis=1)
-    g_best = np.take_along_axis(gain, f_best[:, None], axis=1)[:, 0]
-    b_best = np.take_along_axis(bins_choice, f_best[:, None], axis=1)[:, 0]
-    return g_best, f_best, b_best, node_tot
+    return np.where(valid, gain, -np.inf), node_tot
 
 
 def _ranges_concat(starts: np.ndarray, lens: np.ndarray) -> np.ndarray:
@@ -750,8 +756,9 @@ def _grow_trees(Xb: np.ndarray, y: np.ndarray, tasks: Sequence[tuple],
         Xb_dev = None if _is_streamed(Xb) else jnp.asarray(
             np.ascontiguousarray(Xb, dtype=np.int32))
         dt_name = str(_jax.dtypes.canonicalize_dtype(np.float64))
+        from ..kernels import interpret_mode
         jax_pallas = (_JAX_USE_PALLAS if _JAX_USE_PALLAS is not None
-                      else hops.pallas_supported())
+                      else not interpret_mode())
     else:
         Xb_k = Xb
     yc = y.astype(np.int64) if cls else np.asarray(y, dtype=np.float64)

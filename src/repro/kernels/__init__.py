@@ -1,3 +1,16 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the paper's compute hot spots: split histograms,
+dense proximity blocks and leaf routing (each ``<name>.py`` + ``ops.py`` +
+``ref.py``).
+
+All three are 32-bit kernels.  One rule decides how they run: interpret
+mode on the CPU backend, compiled everywhere else — and a kernel that the
+accelerator's compiler refuses raises there, it never falls back to the
+interpreter or to the jnp reference.
+"""
+from __future__ import annotations
+
+
+def interpret_mode() -> bool:
+    """True iff Pallas kernels run in interpret mode: on the CPU backend."""
+    import jax
+    return jax.default_backend() == "cpu"

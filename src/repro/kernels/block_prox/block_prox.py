@@ -1,19 +1,23 @@
 """Pallas TPU kernel: dense SWLC proximity block materialization.
 
 For a (block_q × block_w) tile of the proximity matrix the kernel holds the
-leaf-code and weight tiles of both sides in VMEM — (block, T) each — and
-accumulates T masked rank-1 updates on the VPU:
+query tile as (block_q, T) and the reference tile *transposed* as
+(T, block_w) in VMEM, and accumulates one masked rank-1 update per tree on
+the VPU:
 
-    acc += (q[:, t] ⊗ w[:, t]) ⊙ (gl_q[:, t] == gl_w[:, t]ᵀ)
+    acc += (q[:, t] ⊗ w[t, :]) ⊙ (gl_q[:, t] == gl_w[t, :])
+
+Every update is a lane-dense (block_q, block_w) operation: the query column
+broadcasts along lanes, the reference row along sublanes, and the tree
+index is static, so no dynamic slice or 3-D intermediate is lowered.
 
 Work is block_q·block_w·T per tile — i.e. the naive-pairwise cost, but only
 for the *requested* blocks (visualization tiles, k-NN re-ranking, medoid
 queries).  The full kernel never goes through here; it uses the factored
 segment-sum path (core.jax_ops) which keeps the paper's O(N T λ̄) bound.
 
-Trees are processed in chunks of ``t_chunk`` so each update is a
-(block_q, t_chunk) × (block_w, t_chunk) broadcast rather than T scalar steps.
-VMEM: 2·block·T·8 bytes for inputs + block_q·block_w·4 for the accumulator.
+VMEM: double-buffered (block, T) input tiles of both sides plus the
+(block_q, block_w) output block.
 """
 from __future__ import annotations
 
@@ -26,72 +30,48 @@ from jax.experimental import pallas as pl
 __all__ = ["block_prox_pallas"]
 
 
-def _block_prox_kernel(glq_ref, q_ref, glw_ref, w_ref, out_ref, *, t_chunk: int):
-    glq = glq_ref[...]            # (bq, T)
-    qv = q_ref[...]
-    glw = glw_ref[...]            # (bw, T)
-    wv = w_ref[...]
-    bq, T = glq.shape
-    bw = glw.shape[0]
-    nchunks = T // t_chunk
-
-    def body(c, acc):
-        s = c * t_chunk
-        gq = jax.lax.dynamic_slice(glq, (0, s), (bq, t_chunk))
-        gw = jax.lax.dynamic_slice(glw, (0, s), (bw, t_chunk))
-        qq = jax.lax.dynamic_slice(qv, (0, s), (bq, t_chunk))
-        ww = jax.lax.dynamic_slice(wv, (0, s), (bw, t_chunk))
-        coll = (gq[:, None, :] == gw[None, :, :])
-        contrib = jnp.where(coll, qq[:, None, :] * ww[None, :, :], 0.0)
-        return acc + contrib.sum(axis=-1)
-
-    acc = jax.lax.fori_loop(0, nchunks, body,
-                            jnp.zeros((bq, bw), dtype=qv.dtype))
+def _block_prox_kernel(glq_ref, q_ref, glwt_ref, wt_ref, out_ref):
+    acc = jnp.zeros(out_ref.shape, out_ref.dtype)
+    for t in range(glq_ref.shape[1]):     # static: one update per tree
+        coll = glq_ref[:, t:t + 1] == glwt_ref[t:t + 1, :]
+        acc = acc + jnp.where(coll, q_ref[:, t:t + 1] * wt_ref[t:t + 1, :],
+                              0.0)
     out_ref[...] = acc
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_q", "block_w", "t_chunk",
-                                    "interpret", "dtype"))
+                   static_argnames=("block_q", "block_w", "interpret",
+                                    "dtype"))
 def block_prox_pallas(gl_q: jax.Array, q: jax.Array, gl_w: jax.Array,
                       w: jax.Array, block_q: int = 256, block_w: int = 256,
-                      t_chunk: int = 8, interpret: bool = False,
+                      interpret: bool = False,
                       dtype=jnp.float32) -> jax.Array:
     """(Nq, Nw) proximity block in ``dtype``; inputs as in ``ref.block_prox_ref``.
 
-    float64 requires jax x64 mode and is only supported off-TPU (interpret).
+    float64 requires jax x64 mode and interpret mode (the TPU has no f64
+    vector unit); ``ops.block_prox`` enforces that.
     """
     nq, T = gl_q.shape
     nw = gl_w.shape[0]
-    # pad T to a multiple of t_chunk with a collision-free sentinel tree
-    t_pad = (T + t_chunk - 1) // t_chunk * t_chunk
-    if t_pad != T:
-        pq, pw = t_pad - T, t_pad - T
-        gl_q = jnp.pad(gl_q, ((0, 0), (0, pq)), constant_values=-1)
-        gl_w = jnp.pad(gl_w, ((0, 0), (0, pw)), constant_values=-2)
-        q = jnp.pad(q, ((0, 0), (0, pq)))
-        w = jnp.pad(w, ((0, 0), (0, pw)))
     nq_pad = (nq + block_q - 1) // block_q * block_q
     nw_pad = (nw + block_w - 1) // block_w * block_w
-    if nq_pad != nq:
-        gl_q = jnp.pad(gl_q, ((0, nq_pad - nq), (0, 0)), constant_values=-1)
-        q = jnp.pad(q, ((0, nq_pad - nq), (0, 0)))
-    if nw_pad != nw:
-        gl_w = jnp.pad(gl_w, ((0, nw_pad - nw), (0, 0)), constant_values=-2)
-        w = jnp.pad(w, ((0, nw_pad - nw), (0, 0)))
+    # padded rows carry collision-free sentinel leaves and zero weight
+    gl_q = jnp.pad(gl_q, ((0, nq_pad - nq), (0, 0)), constant_values=-1)
+    q = jnp.pad(q.astype(dtype), ((0, nq_pad - nq), (0, 0)))
+    gl_wt = jnp.pad(gl_w, ((0, nw_pad - nw), (0, 0)), constant_values=-2).T
+    wt = jnp.pad(w.astype(dtype), ((0, nw_pad - nw), (0, 0))).T
 
-    grid = (nq_pad // block_q, nw_pad // block_w)
     out = pl.pallas_call(
-        functools.partial(_block_prox_kernel, t_chunk=t_chunk),
-        grid=grid,
+        _block_prox_kernel,
+        grid=(nq_pad // block_q, nw_pad // block_w),
         in_specs=[
-            pl.BlockSpec((block_q, t_pad), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_q, t_pad), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_w, t_pad), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_w, t_pad), lambda i, j: (j, 0)),
+            pl.BlockSpec((block_q, T), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_q, T), lambda i, j: (i, 0)),
+            pl.BlockSpec((T, block_w), lambda i, j: (0, j)),
+            pl.BlockSpec((T, block_w), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_q, block_w), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nq_pad, nw_pad), dtype),
         interpret=interpret,
-    )(gl_q, q.astype(dtype), gl_w, w.astype(dtype))
+    )(gl_q, q, gl_wt, wt)
     return out[:nq, :nw]

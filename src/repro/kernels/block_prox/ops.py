@@ -1,10 +1,12 @@
 """Jit'd wrapper for SWLC block materialization."""
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
-import numpy as np
 
+from .. import interpret_mode
 from .block_prox import block_prox_pallas
 from .ref import block_prox_ref
 
@@ -13,17 +15,25 @@ __all__ = ["block_prox"]
 
 def block_prox(gl_q, q, gl_w, w, block_q: int = 256, block_w: int = 256,
                use_pallas: bool = True, dtype=jnp.float32) -> jax.Array:
-    """``dtype`` selects the accumulator/output precision; float64 needs jax
-    x64 mode and falls back to float32 on real TPUs (no f64 VPU support)."""
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu and dtype == jnp.float64:
-        dtype = jnp.float32
+    """``dtype`` selects the accumulator/output precision.
+
+    The compiled kernel is float32 only: asking it for float64 raises
+    (callers that want an f64 result on an accelerator pass float32 and
+    upcast, saying so).  In interpret mode float64 needs jax x64 mode.
+    """
+    interpret = interpret_mode()
+    if use_pallas and not interpret and jnp.dtype(dtype) != jnp.float32:
+        raise ValueError(
+            f"compiled block_prox computes in float32 only, got {dtype}; "
+            "pass dtype=float32")
     gl_q = jnp.asarray(gl_q, jnp.int32)
     gl_w = jnp.asarray(gl_w, jnp.int32)
     q = jnp.asarray(q, dtype)
     w = jnp.asarray(w, dtype)
-    if use_pallas:
+    if not use_pallas:
+        return block_prox_ref(gl_q, q, gl_w, w)
+    # compiled, the kernel is 32-bit throughout (int32 index maps)
+    with contextlib.nullcontext() if interpret else jax.enable_x64(False):
         return block_prox_pallas(gl_q, q, gl_w, w, block_q=block_q,
-                                 block_w=block_w, interpret=not on_tpu,
+                                 block_w=block_w, interpret=interpret,
                                  dtype=dtype)
-    return block_prox_ref(gl_q, q, gl_w, w)

@@ -11,6 +11,13 @@ D·bins) matmul — exactly MXU shape.  The grid walks sample tiles and
 accumulates into the same output block (sequential TPU grid ⇒ safe
 read-modify-write).
 
+Layout: every operand is lane-dense.  Samples run along lanes — the codes
+arrive transposed as (D, tile), node/class ids and payloads as (1, tile) /
+(K, tile) rows — so both one-hots are built transposed, Aᵀ (nodes·C, tile)
+and Bᵀ (D·bins, tile), from 2-D iota compares, and the contraction is one
+``dot_general`` over the tile axis.  No 3-D intermediate exists, so the
+VMEM a call needs is a sum of padded 2-D buffers (``hist_vmem_bytes``).
+
 Two kernel variants share that structure:
 
   ``histogram_pallas``  per-(node, class) weight sums — classification,
@@ -18,12 +25,10 @@ Two kernel variants share that structure:
                         regression / gradient boosting; the payload matrix
                         ``wm`` carries one column per accumulated moment.
 
-VMEM: the whole (nodes·C, D·bins) accumulator block is resident alongside
-the two one-hots — ``tile·(nodes·C + D·bins)·4`` bytes for the one-hots
-plus ``nodes·C·D·bins·4`` for the accumulator.  Both entry points *enforce*
-that budget (``vmem_budget``) and raise instead of silently emitting a
-block that cannot fit; the ``ops.py`` wrapper chunks nodes AND features so
-callers never have to think about it.
+Both entry points *enforce* ``vmem_budget`` — they raise instead of
+emitting a block that cannot fit, and hand the same number to the compiler
+as its scoped-VMEM limit; the ``ops.py`` wrapper chunks nodes AND features
+so callers never have to think about it.
 """
 from __future__ import annotations
 
@@ -32,28 +37,41 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["histogram_pallas", "moments_pallas", "hist_vmem_bytes",
            "DEFAULT_VMEM_BUDGET"]
 
-# Per-core VMEM we allow one histogram call to occupy.  Real TPUs have
-# ~16 MiB/core; keep headroom for double buffering of the input tiles.
+# Per-core VMEM we allow one histogram call to occupy.  v5e's default
+# scoped limit is 16 MiB per kernel; keep headroom below it.
 DEFAULT_VMEM_BUDGET = 12 << 20
+
+_DOT_TN = (((1,), (1,)), ((), ()))     # contract the tile (lane) axis
+
+
+def _padded(rows: int, cols: int) -> int:
+    """f32/int32 elements of a 2-D VMEM buffer in (8, 128) tiles."""
+    return -(-rows // 8) * 8 * (-(-cols // 128) * 128)
 
 
 def hist_vmem_bytes(tile: int, d: int, n_nodes: int, n_bins: int,
                     n_channels: int) -> int:
-    """Estimated VMEM residency of one kernel invocation, in bytes.
+    """Upper bound on the VMEM one kernel invocation allocates, in bytes.
 
-    Counts the (nodes·C, d·bins) f32 accumulator, the A one-hot twice (the
-    weighted build materializes a (tile, nodes, C) transient before the
-    reshape), the B one-hot, and the int32/f32 input tiles.
+    Counts, in (8, 128)-padded 4-byte tiles: the (rows, d·bins) output
+    block double-buffered plus the matmul result and the accumulate
+    temporary; the Bᵀ one-hot with its per-feature compare parts; the Aᵀ
+    one-hot with its iota and mask; and the double-buffered input tiles
+    (codes, ids and payload rows).  ``rows`` is nodes·channels for either
+    kernel.  ``tests/test_tpu_compile.py`` compiles both kernels for v5e
+    with this number as the compiler's scoped-VMEM limit.
     """
-    acc = n_nodes * n_channels * d * n_bins
-    a = tile * n_nodes * n_channels
-    b = tile * d * n_bins
-    inputs = tile * d + 4 * tile
-    return 4 * (acc + 2 * a + b + inputs)
+    rows = n_nodes * n_channels
+    acc = _padded(rows, d * n_bins)
+    b = _padded(d * n_bins, tile)
+    a = _padded(rows, tile)
+    inputs = _padded(d, tile) + _padded(n_channels, tile) + 2 * _padded(1, tile)
+    return 4 * (4 * acc + 2 * b + 3 * a + 2 * inputs)
 
 
 def _check_vmem(tile: int, d: int, n_nodes: int, n_bins: int,
@@ -68,41 +86,74 @@ def _check_vmem(tile: int, d: int, n_nodes: int, n_bins: int,
             "(it sizes blocks to fit), or raise vmem_budget explicitly")
 
 
-def _pad_samples(tile, xb, node, w_cols):
-    n = xb.shape[0]
-    n_pad = (n + tile - 1) // tile * tile
-    if n_pad != n:
-        pad = n_pad - n
-        xb = jnp.pad(xb, ((0, pad), (0, 0)))
-        node = jnp.pad(node, (0, pad))
-        w_cols = [jnp.pad(c, ((0, pad),) + ((0, 0),) * (c.ndim - 1))
-                  for c in w_cols]       # zero weight -> no contribution
-    return n_pad, xb, node, w_cols
+def _bin_onehot_t(xbt: jax.Array, n_bins: int) -> jax.Array:
+    """(D, tile) codes -> (D·bins, tile) f32 transposed bin one-hot."""
+    d, tile = xbt.shape
+    iota = jax.lax.broadcasted_iota(jnp.int32, (n_bins, tile), 0)
+    return jnp.concatenate(
+        [(iota == xbt[f:f + 1, :]).astype(jnp.float32) for f in range(d)],
+        axis=0)
 
 
-def _hist_kernel(xb_ref, node_ref, y_ref, w_ref, out_ref, *,
-                 n_nodes: int, n_bins: int, n_classes: int):
-    i = pl.program_id(0)
+def _onehot_t(ids: jax.Array, n: int) -> jax.Array:
+    """(1, tile) ids -> (n, tile) f32 transposed one-hot."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (n, ids.shape[1]), 0)
+    return (iota == ids).astype(jnp.float32)
 
-    xb = xb_ref[...]            # (tile, D)
-    node = node_ref[...]        # (tile, 1)
-    y = y_ref[...]              # (tile, 1)
-    w = w_ref[...]              # (tile, 1)
-    tile, d = xb.shape
 
-    nc = node[:, 0] * n_classes + y[:, 0]                       # (tile,)
-    A = (nc[:, None] == jnp.arange(n_nodes * n_classes)[None, :])
-    A = A.astype(jnp.float32) * w                               # (tile, nodes*C)
-    B = (xb[:, :, None] == jnp.arange(n_bins)[None, None, :])
-    B = B.astype(jnp.float32).reshape(tile, d * n_bins)         # (tile, D*bins)
+def _accumulate(out_ref, at: jax.Array, bt: jax.Array) -> None:
+    partial = jax.lax.dot_general(at, bt, _DOT_TN,
+                                  precision=jax.lax.Precision.HIGHEST,
+                                  preferred_element_type=jnp.float32)
 
-    partial = jnp.dot(A.T, B, preferred_element_type=jnp.float32)
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
     out_ref[...] += partial
+
+
+def _hist_kernel(xbt_ref, node_ref, y_ref, w_ref, out_ref, *,
+                 n_nodes: int, n_bins: int, n_classes: int):
+    # xbt (D, tile); node / y / w (1, tile)
+    nc = node_ref[...] * n_classes + y_ref[...]
+    at = _onehot_t(nc, n_nodes * n_classes) * w_ref[...]   # (nodes·C, tile)
+    _accumulate(out_ref, at, _bin_onehot_t(xbt_ref[...], n_bins))
+
+
+def _moments_kernel(xbt_ref, node_ref, wmt_ref, out_ref, *,
+                    n_nodes: int, n_bins: int, n_mom: int):
+    # xbt (D, tile); node (1, tile); wmt (K, tile) payload rows
+    oh = _onehot_t(node_ref[...], n_nodes)                  # (nodes, tile)
+    wmt = wmt_ref[...]
+    at = jnp.concatenate([oh * wmt[k:k + 1, :] for k in range(n_mom)],
+                         axis=0)                            # (K·nodes, tile)
+    _accumulate(out_ref, at, _bin_onehot_t(xbt_ref[...], n_bins))
+
+
+def _call(kernel, tile: int, rows: int, n_bins: int, xb, node, extra,
+          interpret: bool, vmem_budget: int):
+    """Shared pallas_call: pad samples to the tile, transpose to lane-dense
+    rows, walk the sample tiles into one resident (rows, D·bins) block."""
+    n, d = xb.shape
+    n_pad = -(-n // tile) * tile
+    pad = n_pad - n                    # zero weight -> no contribution
+    xbt = jnp.pad(xb.astype(jnp.int32), ((0, pad), (0, 0))).T
+    node = jnp.pad(node.astype(jnp.int32), (0, pad))[None, :]
+    extra = [jnp.pad(e, ((0, 0), (0, pad))) for e in extra]
+    return pl.pallas_call(
+        kernel,
+        grid=(n_pad // tile,),
+        in_specs=[pl.BlockSpec((d, tile), lambda i: (0, i)),
+                  pl.BlockSpec((1, tile), lambda i: (0, i))]
+        + [pl.BlockSpec((e.shape[0], tile), lambda i: (0, i)) for e in extra],
+        out_specs=pl.BlockSpec((rows, d * n_bins), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, d * n_bins), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(vmem_budget)),
+        interpret=interpret,
+    )(xbt, node, *extra)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -112,52 +163,14 @@ def histogram_pallas(xb: jax.Array, node: jax.Array, y: jax.Array,
                      tile: int = 512, interpret: bool = False,
                      vmem_budget: int = DEFAULT_VMEM_BUDGET) -> jax.Array:
     """Returns (n_nodes, D, n_bins, n_classes) float32 class histograms."""
-    n, d = xb.shape
+    d = xb.shape[1]
     _check_vmem(tile, d, n_nodes, n_bins, n_classes, vmem_budget)
-    n_pad, xb, node, (y, w) = _pad_samples(tile, xb, node, [y, w])
-
-    out = pl.pallas_call(
-        functools.partial(_hist_kernel, n_nodes=n_nodes, n_bins=n_bins,
-                          n_classes=n_classes),
-        grid=(n_pad // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, d), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((n_nodes * n_classes, d * n_bins),
-                               lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_nodes * n_classes, d * n_bins),
-                                       jnp.float32),
-        interpret=interpret,
-    )(xb.astype(jnp.int32), node.astype(jnp.int32)[:, None],
-      y.astype(jnp.int32)[:, None], w.astype(jnp.float32)[:, None])
+    kernel = functools.partial(_hist_kernel, n_nodes=n_nodes, n_bins=n_bins,
+                               n_classes=n_classes)
+    out = _call(kernel, tile, n_nodes * n_classes, n_bins, xb, node,
+                [y.astype(jnp.int32)[None, :],
+                 w.astype(jnp.float32)[None, :]], interpret, vmem_budget)
     return out.reshape(n_nodes, n_classes, d, n_bins).transpose(0, 2, 3, 1)
-
-
-def _moments_kernel(xb_ref, node_ref, wm_ref, out_ref, *,
-                    n_nodes: int, n_bins: int, n_mom: int):
-    i = pl.program_id(0)
-
-    xb = xb_ref[...]            # (tile, D)
-    node = node_ref[...]        # (tile, 1)
-    wm = wm_ref[...]            # (tile, K) payload columns
-    tile, d = xb.shape
-
-    A = (node[:, 0][:, None] == jnp.arange(n_nodes)[None, :])
-    A = A.astype(jnp.float32)                                   # (tile, nodes)
-    A = (A[:, :, None] * wm[:, None, :]).reshape(tile, n_nodes * n_mom)
-    B = (xb[:, :, None] == jnp.arange(n_bins)[None, None, :])
-    B = B.astype(jnp.float32).reshape(tile, d * n_bins)
-
-    partial = jnp.dot(A.T, B, preferred_element_type=jnp.float32)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += partial
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -170,26 +183,13 @@ def moments_pallas(xb: jax.Array, node: jax.Array, wm: jax.Array,
 
     ``wm`` is (N, n_mom): one column per accumulated moment — the trainer
     passes (w, w·y, w·y²) so regression/GBT split scoring gets its
-    (Σw, Σwy, Σwy²) channels from the same MXU contraction.
+    (Σw, Σwy, Σwy²) channels from the same MXU contraction.  Output rows
+    are moment-major inside the kernel and transposed back here.
     """
-    n, d = xb.shape
+    d = xb.shape[1]
     _check_vmem(tile, d, n_nodes, n_bins, n_mom, vmem_budget)
-    n_pad, xb, node, (wm,) = _pad_samples(tile, xb, node, [wm])
-
-    out = pl.pallas_call(
-        functools.partial(_moments_kernel, n_nodes=n_nodes, n_bins=n_bins,
-                          n_mom=n_mom),
-        grid=(n_pad // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, d), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tile, n_mom), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((n_nodes * n_mom, d * n_bins),
-                               lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_nodes * n_mom, d * n_bins),
-                                       jnp.float32),
-        interpret=interpret,
-    )(xb.astype(jnp.int32), node.astype(jnp.int32)[:, None],
-      wm.astype(jnp.float32))
-    return out.reshape(n_nodes, n_mom, d, n_bins).transpose(0, 2, 3, 1)
+    kernel = functools.partial(_moments_kernel, n_nodes=n_nodes,
+                               n_bins=n_bins, n_mom=n_mom)
+    out = _call(kernel, tile, n_nodes * n_mom, n_bins, xb, node,
+                [wm.astype(jnp.float32).T], interpret, vmem_budget)
+    return out.reshape(n_mom, n_nodes, d, n_bins).transpose(1, 2, 3, 0)

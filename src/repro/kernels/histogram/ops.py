@@ -2,15 +2,17 @@
 
 Responsibilities (all fixed here so the kernels stay simple):
 
-  * **Interpret gating.** Compiled pallas lowering is probed once per jax
-    backend (a tiny kernel is actually lowered+run, not guessed from the
-    platform name), so GPUs get the compiled Triton path instead of being
-    silently forced onto the interpreter; callers can override with
-    ``interpret=``.  The resolved choice is logged once.
+  * **Interpret gating.** Interpret mode on the CPU backend, compiled
+    kernels everywhere else (``kernels.interpret_mode``); callers can
+    override with ``interpret=``.  A kernel the accelerator's compiler
+    refuses raises — nothing falls back.  The kernels are 32-bit, so they
+    are traced with x64 off whatever the caller's scope.
   * **Node chunking with pre-partitioned sample ranges.** Above
     ``max_node_chunk`` the samples are stably sorted by node once and each
-    chunk's kernel call sees ONLY its own sample range — the old path
-    rescanned (and zero-weighted) all N samples per chunk.
+    chunk's kernel call sees ONLY its own sample range.
+  * **Bucketed shapes.** Each call's sample count is zero-weight padded to
+    a power of two (at least one tile) and its node count to a power of
+    two, so a fit compiles log-many kernel variants, not one per chunk.
   * **Feature chunking.** The kernel emits one resident
     ``(nodes·C, d·bins)`` accumulator block; wide ``d·bins`` is split into
     feature blocks sized so the whole invocation fits ``vmem_budget``
@@ -18,63 +20,27 @@ Responsibilities (all fixed here so the kernels stay simple):
 """
 from __future__ import annotations
 
-import logging
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import interpret_mode
 from .histogram import (DEFAULT_VMEM_BUDGET, hist_vmem_bytes,
                         histogram_pallas, moments_pallas)
 from .ref import histogram_ref, moments_ref
 
-__all__ = ["histogram", "moments", "pallas_supported", "resolve_interpret"]
-
-_log = logging.getLogger(__name__)
-_SUPPORTED: dict = {}
-_LOGGED = False
-
-
-def pallas_supported(backend: Optional[str] = None) -> bool:
-    """True iff compiled (non-interpret) pallas lowering works on ``backend``.
-
-    Probed by lowering+running a tiny kernel once per backend and cached —
-    the platform name alone is not trusted (e.g. CPU rejects compiled mode,
-    and a GPU build without Triton support would too).
-    """
-    backend = backend or jax.default_backend()
-    if backend not in _SUPPORTED:
-        try:
-            from jax.experimental import pallas as pl
-
-            def _probe(x_ref, o_ref):
-                o_ref[...] = x_ref[...] + 1.0
-
-            out = pl.pallas_call(
-                _probe,
-                out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
-                interpret=False,
-            )(jnp.zeros((8, 128), jnp.float32))
-            jax.block_until_ready(out)
-            _SUPPORTED[backend] = True
-        except Exception:   # lowering/compile not available -> interpret
-            _SUPPORTED[backend] = False
-    return _SUPPORTED[backend]
+__all__ = ["histogram", "moments", "resolve_interpret"]
 
 
 def resolve_interpret(interpret: Optional[bool] = None) -> bool:
-    """Resolve the interpret flag: caller override wins, else probe."""
-    global _LOGGED
-    if interpret is None:
-        interpret = not pallas_supported()
-    interpret = bool(interpret)
-    if not _LOGGED:
-        _log.info("pallas histogram kernels: %s mode on %r backend",
-                  "interpret" if interpret else "compiled",
-                  jax.default_backend())
-        _LOGGED = True
-    return interpret
+    """Resolve the interpret flag: caller override wins, else CPU-only."""
+    return interpret_mode() if interpret is None else bool(interpret)
+
+
+def _pow2(n: int) -> int:
+    return 1 << max(0, n - 1).bit_length()
 
 
 def _feature_blocks(d: int, tile: int, n_nodes: int, n_bins: int,
@@ -88,34 +54,54 @@ def _feature_blocks(d: int, tile: int, n_nodes: int, n_bins: int,
 
 
 def _node_chunks(node: np.ndarray, n_nodes: int, max_node_chunk: int):
-    """Stable-sort samples by node once; yield (c0, c1, i0, i1) chunk spans.
-
-    Returns (order, spans): ``order`` re-sorts every per-sample array so
-    chunk ``[c0, c1)`` owns exactly the sample range ``order[i0:i1]`` — each
-    chunk's kernel call scans only its own samples instead of all N.
-    """
+    """Stable-sort samples by node once; return (c0, c1, sample rows) per
+    chunk, each chunk owning exactly its own samples."""
+    if n_nodes <= max_node_chunk:
+        return [(0, n_nodes, None)]
     order = np.argsort(node, kind="stable")
     node_sorted = node[order]
     starts = np.arange(0, n_nodes, max_node_chunk)
     ends = np.minimum(starts + max_node_chunk, n_nodes)
     i0 = np.searchsorted(node_sorted, starts, side="left")
     i1 = np.searchsorted(node_sorted, ends, side="left")
-    return order, list(zip(starts.tolist(), ends.tolist(),
-                           i0.tolist(), i1.tolist()))
+    return [(int(c0), int(c1), order[a:b])
+            for c0, c1, a, b in zip(starts, ends, i0, i1)]
 
 
-def _dispatch(call_one, nd_shape, node, n_nodes: int,
-              max_node_chunk: int):
-    """Shared node-chunking driver: ``call_one(sel, base, nc)`` computes the
-    histogram of ``nc`` node slots for the (host-index) sample selection
-    ``sel`` with node ids rebased by ``base``."""
-    if n_nodes <= max_node_chunk:
-        return call_one(None, 0, n_nodes)
-    order, spans = _node_chunks(node, n_nodes, max_node_chunk)
+def _run(kernel, xb, node, cols, n_nodes: int, n_bins: int, n_ch: int,
+         tile: int, max_node_chunk: int, interpret: bool,
+         vmem_budget: int) -> jax.Array:
+    """Shared chunking driver.  ``cols`` are the per-sample payload arrays
+    handed to ``kernel`` after (codes, node); padded samples get zero
+    payload and so contribute nothing."""
+    n, d = xb.shape
     outs = []
-    for c0, c1, i0, i1 in spans:
-        outs.append(call_one(order[i0:i1], c0, c1 - c0))
-    return jnp.concatenate(outs, axis=0)
+    for c0, c1, sel in _node_chunks(np.asarray(node), n_nodes,
+                                    max_node_chunk):
+        nc = c1 - c0
+        m = n if sel is None else len(sel)
+        if m == 0:
+            outs.append(jnp.zeros((nc, d, n_bins, n_ch), jnp.float32))
+            continue
+        mp, ncp = max(tile, _pow2(m)), _pow2(nc)
+        idx = np.zeros(mp, np.int32)
+        idx[:m] = np.arange(m) if sel is None else sel
+        live = np.zeros((mp,) + (1,) * (cols[-1].ndim - 1), np.float32)
+        live[:m] = 1.0
+        with jax.enable_x64(False):
+            ix = jnp.asarray(idx)
+            xb_c = xb[ix]
+            node_c = jnp.where(live.reshape(mp) > 0, node[ix] - c0, 0)
+            cols_c = [c[ix] for c in cols[:-1]] + [cols[-1][ix] * live]
+            db = _feature_blocks(d, tile, ncp, n_bins, n_ch, vmem_budget)
+            parts = [kernel(xb_c[:, f0:f0 + db], node_c, *cols_c, ncp,
+                            n_bins, n_ch, tile=tile, interpret=interpret,
+                            vmem_budget=vmem_budget)
+                     for f0 in range(0, d, db)]
+            h = parts[0] if len(parts) == 1 else \
+                jnp.concatenate(parts, axis=1)
+        outs.append(h[:nc])
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
 
 
 def histogram(xb, node, y, w, n_nodes: int, n_bins: int, n_classes: int,
@@ -127,35 +113,11 @@ def histogram(xb, node, y, w, n_nodes: int, n_bins: int, n_classes: int,
     node = jnp.asarray(node, jnp.int32)
     y = jnp.asarray(y, jnp.int32)
     w = jnp.asarray(w, jnp.float32)
-    n, d = xb.shape
     if not use_pallas:
         return histogram_ref(xb, node, y, w, n_nodes, n_bins, n_classes)
-    interp = resolve_interpret(interpret)
-    if n == 0:
-        return jnp.zeros((n_nodes, d, n_bins, n_classes), jnp.float32)
-
-    node_np = np.asarray(node)
-
-    def call_one(sel, base, nc):
-        xb_c, node_c, y_c, w_c = xb, node, y, w
-        if sel is not None:
-            if len(sel) == 0:
-                return jnp.zeros((nc, d, n_bins, n_classes), jnp.float32)
-            idx = jnp.asarray(sel)
-            xb_c, node_c = xb[idx], node[idx] - base
-            y_c, w_c = y[idx], w[idx]
-        db = _feature_blocks(d, tile, nc, n_bins, n_classes, vmem_budget)
-        if db >= d:
-            return histogram_pallas(xb_c, node_c, y_c, w_c, nc, n_bins,
-                                    n_classes, tile=tile, interpret=interp,
-                                    vmem_budget=vmem_budget)
-        parts = [histogram_pallas(xb_c[:, f0:min(f0 + db, d)], node_c, y_c,
-                                  w_c, nc, n_bins, n_classes, tile=tile,
-                                  interpret=interp, vmem_budget=vmem_budget)
-                 for f0 in range(0, d, db)]
-        return jnp.concatenate(parts, axis=1)
-
-    return _dispatch(call_one, None, node_np, n_nodes, max_node_chunk)
+    return _run(histogram_pallas, xb, node, [y, w], n_nodes, n_bins,
+                n_classes, tile, max_node_chunk, resolve_interpret(interpret),
+                vmem_budget)
 
 
 def moments(xb, node, wm, n_nodes: int, n_bins: int,
@@ -170,32 +132,8 @@ def moments(xb, node, wm, n_nodes: int, n_bins: int,
     xb = jnp.asarray(xb, jnp.int32)
     node = jnp.asarray(node, jnp.int32)
     wm = jnp.asarray(wm, jnp.float32)
-    n, d = xb.shape
-    n_mom = wm.shape[1]
     if not use_pallas:
-        return moments_ref(xb, node, wm, n_nodes, n_bins, n_mom)
-    interp = resolve_interpret(interpret)
-    if n == 0:
-        return jnp.zeros((n_nodes, d, n_bins, n_mom), jnp.float32)
-
-    node_np = np.asarray(node)
-
-    def call_one(sel, base, nc):
-        xb_c, node_c, wm_c = xb, node, wm
-        if sel is not None:
-            if len(sel) == 0:
-                return jnp.zeros((nc, d, n_bins, n_mom), jnp.float32)
-            idx = jnp.asarray(sel)
-            xb_c, node_c, wm_c = xb[idx], node[idx] - base, wm[idx]
-        db = _feature_blocks(d, tile, nc, n_bins, n_mom, vmem_budget)
-        if db >= d:
-            return moments_pallas(xb_c, node_c, wm_c, nc, n_bins, n_mom,
-                                  tile=tile, interpret=interp,
-                                  vmem_budget=vmem_budget)
-        parts = [moments_pallas(xb_c[:, f0:min(f0 + db, d)], node_c, wm_c,
-                                nc, n_bins, n_mom, tile=tile,
-                                interpret=interp, vmem_budget=vmem_budget)
-                 for f0 in range(0, d, db)]
-        return jnp.concatenate(parts, axis=1)
-
-    return _dispatch(call_one, None, node_np, n_nodes, max_node_chunk)
+        return moments_ref(xb, node, wm, n_nodes, n_bins, wm.shape[1])
+    return _run(moments_pallas, xb, node, [wm], n_nodes, n_bins,
+                wm.shape[1], tile, max_node_chunk,
+                resolve_interpret(interpret), vmem_budget)

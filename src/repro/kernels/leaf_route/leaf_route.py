@@ -1,13 +1,20 @@
 """Pallas TPU kernel: batched root-to-leaf routing.
 
-TPU adaptation of pointer-chasing tree traversal (DESIGN.md §3): the grid is
-(sample tiles × trees); each program routes a VMEM tile of ``block_n``
-samples through one tree with ``max_depth`` branch-free steps of
-gather + compare + select on the lane dimension.  Node arrays for the tree
-live in VMEM (struct-of-arrays), the sample tile is (block_n, D).
+TPU adaptation of pointer-chasing tree traversal (DESIGN.md §3): the grid
+walks sample tiles; each program holds its (block_n, D) sample tile and the
+whole (T, M) struct-of-arrays node tables in VMEM, and routes the tile
+through every tree with ``max_depth`` branch-free steps of gather + compare
++ select, writing one (block_n, T) output block.  Whole-array node blocks
+and a full-T output block keep every block shape legal under the (8, 128)
+tiling rule.
 
-VMEM budget per program: block_n·D·4 (samples) + 4·M·4 (nodes) + block_n·4
-(output) bytes; pick block_n so this stays well under ~16 MB.
+The per-sample gathers from the node tables (``feat[node]``) and from the
+sample row (``x[i, feat]``) are what the v5e compiler refuses to lower, so
+on a TPU this kernel raises the compiler's error: exact device routing is
+open work, and the host routes instead.  Interpret mode runs it anywhere.
+
+VMEM budget per program: block_n·D·4 (samples) + 5·T·M·4 (nodes) +
+block_n·T·4 (output) bytes, double-buffered.
 """
 from __future__ import annotations
 
@@ -23,25 +30,23 @@ __all__ = ["route_pallas"]
 def _route_kernel(x_ref, feat_ref, thr_ref, left_ref, right_ref, lid_ref,
                   out_ref, *, max_depth: int):
     x = x_ref[...]                      # (block_n, D)
-    feat = feat_ref[0]                  # (M,)
-    thr = thr_ref[0]
-    left = left_ref[0]
-    right = right_ref[0]
-    lid = lid_ref[0]
     n = x.shape[0]
-    node0 = jnp.zeros((n,), dtype=jnp.int32)
+    for t in range(feat_ref.shape[0]):  # static: one pass per tree
+        feat, thr = feat_ref[t], thr_ref[t]          # (M,)
+        left, right = left_ref[t], right_ref[t]
 
-    def body(_, node):
-        f = feat[node]                              # gather over nodes
-        internal = f >= 0
-        fi = jnp.where(internal, f, 0)
-        xv = jnp.take_along_axis(x, fi[:, None], axis=1)[:, 0]
-        go_left = xv <= thr[node]
-        nxt = jnp.where(go_left, left[node], right[node])
-        return jnp.where(internal, nxt, node).astype(jnp.int32)
+        def body(_, node):
+            f = feat[node]                          # gather over nodes
+            internal = f >= 0
+            fi = jnp.where(internal, f, 0)
+            xv = jnp.take_along_axis(x, fi[:, None], axis=1)[:, 0]
+            go_left = xv <= thr[node]
+            nxt = jnp.where(go_left, left[node], right[node])
+            return jnp.where(internal, nxt, node).astype(jnp.int32)
 
-    node = jax.lax.fori_loop(0, max_depth, body, node0)
-    out_ref[...] = lid[node][:, None]
+        node = jax.lax.fori_loop(0, max_depth, body,
+                                 jnp.zeros((n,), dtype=jnp.int32))
+        out_ref[:, t:t + 1] = lid_ref[t][node][:, None]
 
 
 @functools.partial(jax.jit, static_argnames=("max_depth", "block_n", "interpret"))
@@ -55,20 +60,14 @@ def route_pallas(x: jax.Array, feature: jax.Array, threshold: jax.Array,
     n_pad = (n + block_n - 1) // block_n * block_n
     if n_pad != n:
         x = jnp.pad(x, ((0, n_pad - n), (0, 0)))
-    grid = (n_pad // block_n, T)
+    node_spec = pl.BlockSpec((T, m), lambda i: (0, 0))
 
     out = pl.pallas_call(
         functools.partial(_route_kernel, max_depth=max_depth),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, m), lambda i, t: (t, 0)),
-            pl.BlockSpec((1, m), lambda i, t: (t, 0)),
-            pl.BlockSpec((1, m), lambda i, t: (t, 0)),
-            pl.BlockSpec((1, m), lambda i, t: (t, 0)),
-            pl.BlockSpec((1, m), lambda i, t: (t, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_n, 1), lambda i, t: (i, t)),
+        grid=(n_pad // block_n,),
+        in_specs=[pl.BlockSpec((block_n, d), lambda i: (i, 0))]
+        + [node_spec] * 5,
+        out_specs=pl.BlockSpec((block_n, T), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, T), jnp.int32),
         interpret=interpret,
     )(x, feature, threshold, left, right, leaf_id)

@@ -1,6 +1,8 @@
 """Jit'd public wrapper for the routing kernel.
 
-Falls back to interpret mode off-TPU so the same call sites work everywhere.
+Interpret mode on the CPU backend; compiled elsewhere — where the v5e
+compiler refuses the kernel's gathers, so ``use_pallas`` raises its error
+on a TPU instead of quietly routing some other way.
 """
 from __future__ import annotations
 
@@ -8,23 +10,22 @@ import jax
 import numpy as np
 
 from ...forest.trees import TreeArrays
+from .. import interpret_mode
 from .leaf_route import route_pallas
 from .ref import route_ref
 
 __all__ = ["route", "route_arrays"]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def route_arrays(x, feature, threshold, left, right, leaf_id, max_depth,
                  block_n: int = 1024, use_pallas: bool = True):
-    if use_pallas:
+    if not use_pallas:
+        return route_ref(x, feature, threshold, left, right, leaf_id,
+                         max_depth)
+    with jax.enable_x64(False):        # 32-bit kernel: int32 index maps
         return route_pallas(x, feature, threshold, left, right, leaf_id,
                             max_depth=max_depth, block_n=block_n,
-                            interpret=not _on_tpu())
-    return route_ref(x, feature, threshold, left, right, leaf_id, max_depth)
+                            interpret=interpret_mode())
 
 
 def route(x: np.ndarray, ta: TreeArrays, block_n: int = 1024,

@@ -6,11 +6,7 @@ touches jax device state.
 from __future__ import annotations
 
 import jax
-
-try:                                   # jax >= 0.4.38
-    from jax.sharding import AxisType
-except ImportError:                    # pragma: no cover — older jax
-    AxisType = None
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh", "compat_mesh",
            "batch_axes", "fsdp_axes", "MODEL_AXIS"]
@@ -19,13 +15,8 @@ MODEL_AXIS = "model"
 
 
 def compat_mesh(shape, axes):
-    """``jax.make_mesh`` with explicit Auto axis types where this jax has
-    ``jax.sharding.AxisType`` (>= 0.4.38); plain mesh (implicitly Auto)
-    otherwise — the 0.4.37 compat shim mirroring ``jax_ops._shard_map``."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with explicit Auto axis types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -111,6 +111,43 @@ def test_engine_rejects_unknown_backend(rf_kernel_cache):
         ProximityEngine(fk.ctx, fk.assignment, backend="torch")
 
 
+def test_block_dtype_float32_only_for_compiled_pallas(rf_kernel_cache,
+                                                     monkeypatch):
+    """Off the CPU the pallas engine asks block_prox for float32 and says
+    so; every other backend's block ops compute in the engine dtype."""
+    import repro.kernels as kernels
+    fk = rf_kernel_cache["gap"]
+    build = lambda be: ProximityEngine(fk.ctx, fk.assignment,
+                                       forest=fk.forest, backend=be)
+    assert build("pallas").block_dtype == np.float64      # interpret mode
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+    assert build("pallas").block_dtype == np.float32
+    assert build("jax").block_dtype == np.float64
+
+
+def test_op_paths_count_the_host_cutover(rf_kernel_cache, monkeypatch):
+    """Training-set topk / squared row sums above the cutover run as host
+    CSR on a device backend and are counted as ``host_cutover``."""
+    from repro.obs.metrics import global_registry
+    fk = rf_kernel_cache["gap"]
+    eng = ProximityEngine(fk.ctx, fk.assignment, forest=fk.forest,
+                          backend="jax")
+    fam = global_registry().counter(
+        "engine_op_path_total", labels=("op", "backend", "path"))
+    count = lambda op, path: fam.labels(op=op, backend="jax",
+                                        path=path).value
+    before = {op: (count(op, "device"), count(op, "host_cutover"))
+              for op in ("topk", "squared_row_sums")}
+    eng.topk(k=3)
+    eng.squared_row_sums()
+    monkeypatch.setattr(ProximityEngine, "_SPARSE_TRAIN_CUTOVER", 10)
+    eng.topk(k=3)
+    eng.squared_row_sums()
+    for op, (dev, cut) in before.items():
+        assert count(op, "device") == dev + 1, op
+        assert count(op, "host_cutover") == cut + 1, op
+
+
 def test_full_kernel_diagonal_without_lil(rf_kernel_cache):
     """Diagonal override keeps CSR structure and exact values (satellite)."""
     import scipy.sparse as sp
@@ -224,13 +261,15 @@ def test_engine_sharded_matmat_multi_device():
         import numpy as np
         from repro.core.api import ForestKernel
         from repro.data.synthetic import gaussian_classes
-        X, y = gaussian_classes(160, d=8, n_classes=3, seed=5)
+        # 162 rows: not a multiple of the 8 devices, so the sharded path
+        # pads its rows
+        X, y = gaussian_classes(162, d=8, n_classes=3, seed=5)
         fk = ForestKernel(kernel_method="gap", n_trees=10, seed=0,
                           engine_backend="jax").fit(X, y)
         ref = ForestKernel(kernel_method="gap", n_trees=10, seed=0)
         ref.forest = fk.forest
         ref.build_kernel_cache()
-        V = np.random.default_rng(0).normal(size=(160, 3))
+        V = np.random.default_rng(0).normal(size=(162, 3))
         np.testing.assert_allclose(fk.engine.matmat(V),
                                    ref.engine.matmat(V), atol=1e-8)
         assert fk.engine.last_matmat_path == "sharded", \\
@@ -243,7 +282,7 @@ def test_engine_sharded_matmat_multi_device():
         from repro.core import jax_ops
         orig = jax_ops.auto_c_chunk
         jax_ops.auto_c_chunk = lambda *a, **k: 3
-        W = np.random.default_rng(1).normal(size=(160, 10))
+        W = np.random.default_rng(1).normal(size=(162, 10))
         np.testing.assert_allclose(fk.engine.matmat(W),
                                    ref.engine.matmat(W), atol=1e-8)
         assert fk.engine.last_matmat_path == "sharded"

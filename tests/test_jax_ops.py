@@ -68,17 +68,13 @@ def test_swlc_matmat_tree_chunked_matches_unchunked():
 
 
 def test_swlc_matmat_large_C_chunked_regression():
-    """ROADMAP PR-1 follow-up: at C large enough that the unchunked
-    (N, T, C) intermediate dominates memory (256·64·4096 ≈ 67M elements,
-    ~268 MB f32 — vs ~256 KB of factors), auto_t_chunk must engage and the
-    chunked product must still match the dense oracle."""
-    from repro.core.jax_ops import auto_t_chunk
+    """At C large enough that an unchunked (N, T, C) intermediate would
+    dominate memory (256·64·4096 ≈ 67M elements, ~268 MB f32 — vs ~256 KB
+    of factors), the engine's one-tree-per-step product (a (N, 1, C)
+    intermediate) must still match the dense oracle."""
     rng = np.random.default_rng(3)
     n, T, lpt, C = 256, 64, 8, 4096
-    tc = auto_t_chunk(n, T, C)
-    assert tc is not None and tc < T, tc                    # chunking engaged
-    assert n * tc * C <= 1 << 24                            # bounded interm.
-    assert auto_t_chunk(256, 64, 4) is None                 # small C: off
+    tc = 1
     gl = _leafset(rng, n, T, lpt)
     q = rng.random((n, T)).astype(np.float32)
     w = rng.random((n, T)).astype(np.float32)

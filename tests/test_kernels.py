@@ -76,6 +76,17 @@ def test_block_prox_padding_no_phantom_collisions():
     np.testing.assert_allclose(got, want, atol=1e-6)
 
 
+def test_compiled_block_prox_refuses_float64(monkeypatch):
+    """Off the CPU the kernel runs compiled, in float32 only: a float64
+    request raises instead of silently computing in float32."""
+    from repro.kernels.block_prox import ops as bp_ops
+    monkeypatch.setattr(bp_ops, "interpret_mode", lambda: False)
+    gl = _rand_leafset(np.random.default_rng(0), 4, 3, 4)
+    q = np.ones((4, 3))
+    with pytest.raises(ValueError, match="float32 only"):
+        block_prox(gl, q, gl, q, dtype=jnp.float64)
+
+
 @settings(max_examples=20, deadline=None)
 @given(nq=st.integers(1, 40), nw=st.integers(1, 40), T=st.integers(1, 12),
        seed=st.integers(0, 2 ** 16))
@@ -206,7 +217,8 @@ def test_histogram_feature_chunking_small_budget():
     """A vmem budget too small for all features at once still gives the
     full-width answer (feature axis is chunked and re-concatenated)."""
     xb, node, y, w = _int_fixture(500, 11, 10, 16, 3, seed=4)
-    budget = hist_vmem_bytes(256, 3, 10, 16, 3) + 1
+    # the wrapper buckets the 10 nodes to 16
+    budget = hist_vmem_bytes(256, 3, 16, 16, 3) + 1
     got = np.asarray(histogram(xb, node, y, w, 10, 16, 3, tile=256,
                                vmem_budget=budget))
     whole = np.asarray(histogram(xb, node, y, w, 10, 16, 3, tile=256))
@@ -235,13 +247,13 @@ def test_histogram_empty_input_is_zero():
 
 
 def test_interpret_resolution_probes_lowering(monkeypatch):
-    """interpret=None must gate on actual compiled-lowering support (CPU:
-    unsupported -> interpret), and an explicit caller override must win."""
-    assert hist_ops.pallas_supported("cpu") is False
+    """interpret=None picks interpret mode on the CPU backend only — any
+    other backend compiles (and a refused compile raises) — and an
+    explicit caller override wins."""
     assert hist_ops.resolve_interpret(None) is True
     assert hist_ops.resolve_interpret(False) is False
     assert hist_ops.resolve_interpret(True) is True
-    monkeypatch.setitem(hist_ops._SUPPORTED, "cpu", True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert hist_ops.resolve_interpret(None) is False
 
 

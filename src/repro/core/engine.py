@@ -45,6 +45,7 @@ import scipy.sparse as sp
 from scipy.sparse.linalg import LinearOperator
 
 from ..forest import _native
+from ..obs.trace import span
 from .factorization import (full_kernel, kernel_block, kernel_matvec_operator,
                             prefix_leaf_contraction, topk_neighbors)
 from .leafmap import build_leaf_map
@@ -69,6 +70,29 @@ def _x64_scope(enabled: bool):
 
     import jax
     return jax.enable_x64(True) if enabled else contextlib.nullcontext()
+
+
+def _stage(*arrays: np.ndarray) -> tuple:
+    """The host arrays on the device, under one ``engine.upload`` span whose
+    ``bytes`` stat is what is staged: each array in the dtype JAX gives it
+    (64-bit stays 64-bit under the caller's x64 scope only).
+
+    The span times the host's part of the staging; the transfers finish
+    on the runtime's threads, and the device waits for them inside the
+    ``engine.fetch`` that follows."""
+    import jax
+    import jax.numpy as jnp
+    n = sum(a.size * jax.dtypes.canonicalize_dtype(a.dtype).itemsize
+            for a in arrays)
+    with span("engine.upload", bytes=int(n)):
+        return tuple(jnp.asarray(a) for a in arrays)
+
+
+def _fetch(*outs) -> tuple:
+    """Device results as host arrays, under one ``engine.fetch`` span: the
+    wait for the device and the copy back."""
+    with span("engine.fetch"):
+        return tuple(np.asarray(o) for o in outs)
 
 
 _DEVICE_BACKENDS = ("jax", "pallas")
@@ -229,19 +253,21 @@ class ProximityEngine:
         """Training state (X=None) or a cached OOS state for a new batch."""
         if X is None:
             return self._train_state
-        key = self._batch_key(np.asarray(X))
+        with span("engine.batch_key"):
+            key = self._batch_key(np.asarray(X))
         hit = self._qs_cache_get(key)
         if hit is not None:
             return hit
         assert self.forest is not None, "OOS queries need the backing forest"
-        leaves = self.forest.apply(X)
+        with span("engine.apply"):
+            leaves = self.forest.apply(X)
         gl = leaves.astype(np.int64) + self.ctx.leaf_offset[None, :]
-        q = np.ascontiguousarray(
-            self.assignment.oos_query_weights(leaves), dtype=self.dtype)
-        state = QueryState(gl=gl, q=q,
-                           Q=build_leaf_map(gl, q, self.total_leaves,
-                                            self.dtype))
-        return self._qs_cache_put(key, state)
+        with span("engine.weights"):
+            q = np.ascontiguousarray(
+                self.assignment.oos_query_weights(leaves), dtype=self.dtype)
+        with span("engine.leaf_map"):
+            Q = build_leaf_map(gl, q, self.total_leaves, self.dtype)
+        return self._qs_cache_put(key, QueryState(gl=gl, q=q, Q=Q))
 
     def _qs_cache_get(self, key: str) -> Optional[QueryState]:
         with self._qs_lock:
@@ -373,7 +399,6 @@ class ProximityEngine:
         return out
 
     def _segment_matmat(self, qs: QueryState, V: np.ndarray) -> np.ndarray:
-        import jax.numpy as jnp
         from . import jax_ops
         n_ref, T = self.gl.shape
         with _x64_scope(self._use_x64):
@@ -386,32 +411,38 @@ class ProximityEngine:
                     # padded output rows are sliced off
                     pad = (-n_ref) % n_dev
                     rows = ((0, pad), (0, 0))
-                    gl_d = jnp.asarray(np.pad(self.gl, rows))
-                    q_d = jnp.asarray(np.pad(self.q, rows))
-                    w_d = jnp.asarray(np.pad(self.w, rows))
+                    gl_d, q_d, w_d = _stage(np.pad(self.gl, rows),
+                                            np.pad(self.q, rows),
+                                            np.pad(self.w, rows))
                     # wide V: split into column blocks so the per-device
                     # (N/devices, T, c) intermediate stays bounded
                     n_loc = (n_ref + pad) // n_dev
                     c = jax_ops.auto_c_chunk(n_loc, T, V.shape[1])
                     c = V.shape[1] if c is None else c
-                    out = np.concatenate([
-                        np.asarray(jax_ops.sharded_swlc_matmat(
-                            mesh, gl_d, q_d, w_d,
-                            jnp.asarray(np.pad(V[:, j0:j0 + c], rows)),
-                            self.total_leaves))
-                        for j0 in range(0, V.shape[1], c)], axis=1)
+                    parts = []
+                    for j0 in range(0, V.shape[1], c):
+                        V_d, = _stage(np.pad(V[:, j0:j0 + c], rows))
+                        with span("engine.dispatch"):
+                            out = jax_ops.sharded_swlc_matmat(
+                                mesh, gl_d, q_d, w_d, V_d, self.total_leaves)
+                        del V_d
+                        parts += _fetch(out)
                     self.last_matmat_path = "sharded"
-                    return out[:n_ref]
+                    return np.concatenate(parts, axis=1)[:n_ref]
             # one tree per step: leaf ids are tree-major, so each step
             # scatters N_ref rows into its own tree's buckets.  A single
             # scatter of all N_ref·T rows costs the TPU compiler minutes.
-            out = jax_ops.swlc_predict(jnp.asarray(qs.gl), jnp.asarray(qs.q),
-                                       jnp.asarray(self.gl),
-                                       jnp.asarray(self.w),
-                                       jnp.asarray(V), self.total_leaves,
-                                       t_chunk=1)
+            staged = _stage(qs.gl, qs.q, self.gl, self.w, V)
+            with span("engine.dispatch"):
+                out = jax_ops.swlc_predict(*staged, self.total_leaves,
+                                           t_chunk=1)
+            # drop the inputs now, as inline arguments would be: their
+            # device buffers then go as soon as the product has read them.
+            # Held through the _fetch below, they stalled about 1 call in
+            # 70 by 1-2 s on a TPU v5e (cause not known)
+            del staged
             self.last_matmat_path = "segment"
-            return np.asarray(out)
+            return _fetch(out)[0]
 
     def operator(self) -> LinearOperator:
         if self.backend == "scipy":
@@ -489,21 +520,27 @@ class ProximityEngine:
             out = _native.prox_block_native(gl_q, q, gl_w, w)
             return out.astype(self.dtype, copy=False)
         if self.backend == "jax":
-            import jax.numpy as jnp
             from .jax_ops import swlc_block
             out = np.empty((len(rows), gl_w.shape[0]), dtype=self.dtype)
             step = self._op_row_chunk(gl_w.shape[0])
             with _x64_scope(self._use_x64):
-                gl_w_d, w_d = jnp.asarray(gl_w), jnp.asarray(w)
+                gl_w_d, w_d = _stage(gl_w, w)
                 for i0 in range(0, len(rows), step):
-                    out[i0:i0 + step] = np.asarray(swlc_block(
-                        jnp.asarray(gl_q[i0:i0 + step]),
-                        jnp.asarray(q[i0:i0 + step]), gl_w_d, w_d))
+                    gl_q_d, q_d = _stage(gl_q[i0:i0 + step],
+                                         q[i0:i0 + step])
+                    with span("engine.dispatch"):
+                        blk = swlc_block(gl_q_d, q_d, gl_w_d, w_d)
+                    del gl_q_d, q_d
+                    out[i0:i0 + step] = _fetch(blk)[0]
             return out
         from ..kernels.block_prox.ops import block_prox
+        # the wrapper stages its own inputs and dispatches the kernel: the
+        # span's bytes are those of the host arrays it is handed
+        handed = gl_q.nbytes + q.nbytes + gl_w.nbytes + w.nbytes
         with _x64_scope(self.block_dtype == np.float64):
-            out = block_prox(gl_q, q, gl_w, w, dtype=self.block_dtype)
-        return np.asarray(out).astype(self.dtype, copy=False)
+            with span("engine.dispatch", bytes=int(handed)):
+                out = block_prox(gl_q, q, gl_w, w, dtype=self.block_dtype)
+        return _fetch(out)[0].astype(self.dtype, copy=False)
 
     def squared_row_sums(self, class_ids: Optional[np.ndarray] = None,
                          n_classes: Optional[int] = None,
@@ -625,20 +662,19 @@ class ProximityEngine:
         val = np.zeros((n, k), dtype=self.dtype)
         gl_w_d = w_d = None
         if self.backend == "jax":
-            import jax.numpy as jnp
             block = min(block, self._op_row_chunk(self.W.shape[0]))
             with _x64_scope(self._use_x64):
-                gl_w_d, w_d = jnp.asarray(self.gl), jnp.asarray(self.w)
+                gl_w_d, w_d = _stage(self.gl, self.w)
         for i0 in range(0, n, block):
             i1 = min(i0 + block, n)
             if self.backend == "jax":
-                import jax.numpy as jnp
                 from .jax_ops import swlc_topk
                 with _x64_scope(self._use_x64):
-                    v, ix = swlc_topk(jnp.asarray(qs.gl[i0:i1]),
-                                      jnp.asarray(qs.q[i0:i1]),
-                                      gl_w_d, w_d, kk)
-                    v, ix = np.asarray(v), np.asarray(ix)
+                    gl_q_d, q_d = _stage(qs.gl[i0:i1], qs.q[i0:i1])
+                    with span("engine.dispatch"):
+                        v, ix = swlc_topk(gl_q_d, q_d, gl_w_d, w_d, kk)
+                    del gl_q_d, q_d
+                    v, ix = _fetch(v, ix)
             else:
                 B = self.kernel_block(np.arange(i0, i1), X_rows=X)
                 part = np.argpartition(B, -kk, axis=1)[:, -kk:]
@@ -736,16 +772,17 @@ class PrefixProximityEngine(ProximityEngine):
         """Contract the parent's routed state instead of re-routing."""
         if X is None:
             return self._train_state
-        key = self._batch_key(np.asarray(X))
+        with span("engine.batch_key"):
+            key = self._batch_key(np.asarray(X))
         hit = self._qs_cache_get(key)
         if hit is not None:
             return hit
         full = self.parent.query_state(X)      # routed once, shared by tiers
         gl = self._gmap[full.gl]
         leaves_k = gl - self._leaf_offset_k[None, :]
-        q = np.ascontiguousarray(
-            self.assignment.oos_query_weights(leaves_k), dtype=self.dtype)
-        state = QueryState(gl=gl, q=q,
-                           Q=build_leaf_map(gl, q, self.total_leaves,
-                                            self.dtype))
-        return self._qs_cache_put(key, state)
+        with span("engine.weights"):
+            q = np.ascontiguousarray(
+                self.assignment.oos_query_weights(leaves_k), dtype=self.dtype)
+        with span("engine.leaf_map"):
+            Q = build_leaf_map(gl, q, self.total_leaves, self.dtype)
+        return self._qs_cache_put(key, QueryState(gl=gl, q=q, Q=Q))

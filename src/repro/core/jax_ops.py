@@ -7,6 +7,9 @@ On TPU we avoid CSR scatter/gather entirely.  The factored kernel apply
   2. gather:  (Pv)[i] = Σ_t q[i,t] · s[gl[i,t]]
 
 Both are O(N·T) with no data-dependent shapes, so they jit/pjit cleanly.
+Each stage runs under a ``jax.named_scope`` of its name (``bucket``,
+``gather``), which the compiled HLO carries in its ``op_name`` metadata,
+so a device trace can tell the two stages' operations apart.
 The distributed version shards samples over the "data" mesh axis and trees
 over the "model" mesh axis: each model shard buckets its own tree slice into
 a private leaf-range (leaf ids are tree-major), so the only collectives are
@@ -76,10 +79,13 @@ def _swlc_product(gl_q: jax.Array, q: jax.Array, gl_w: jax.Array,
     C = V.shape[1]
     out_dtype = jnp.result_type(q.dtype, V.dtype)
     if t_chunk is None or t_chunk >= T:
-        contrib = w[:, :, None] * V[:, None, :]              # (N_w, T, C)
-        s = jax.ops.segment_sum(contrib.reshape(nw * T, -1), gl_w.ravel(),
-                                num_segments=total_leaves)   # (L, C)
-        return (q[:, :, None] * s[gl_q]).sum(axis=1)
+        with jax.named_scope("bucket"):
+            contrib = w[:, :, None] * V[:, None, :]          # (N_w, T, C)
+            s = jax.ops.segment_sum(contrib.reshape(nw * T, -1),
+                                    gl_w.ravel(),
+                                    num_segments=total_leaves)   # (L, C)
+        with jax.named_scope("gather"):
+            return (q[:, :, None] * s[gl_q]).sum(axis=1)
 
     pad = (-T) % t_chunk
     if pad:
@@ -100,8 +106,10 @@ def _swlc_product(gl_q: jax.Array, q: jax.Array, gl_w: jax.Array,
             contrib.reshape(nw * t_chunk, -1), gw.ravel(),
             num_segments=total_leaves + 1)
 
-    s = jax.lax.fori_loop(0, n_chunks, bucket,
-                          jnp.zeros((total_leaves + 1, C), dtype=out_dtype))
+    with jax.named_scope("bucket"):
+        s = jax.lax.fori_loop(0, n_chunks, bucket,
+                              jnp.zeros((total_leaves + 1, C),
+                                        dtype=out_dtype))
 
     def gather(c, out):
         sl = jax.lax.dynamic_slice_in_dim
@@ -109,8 +117,9 @@ def _swlc_product(gl_q: jax.Array, q: jax.Array, gl_w: jax.Array,
         qq = sl(q, c * t_chunk, t_chunk, axis=1)
         return out + (qq[:, :, None] * s[gq]).sum(axis=1)
 
-    return jax.lax.fori_loop(0, n_chunks, gather,
-                             jnp.zeros((nq, C), dtype=out_dtype))
+    with jax.named_scope("gather"):
+        return jax.lax.fori_loop(0, n_chunks, gather,
+                                 jnp.zeros((nq, C), dtype=out_dtype))
 
 
 def swlc_matmat(gl: jax.Array, q: jax.Array, w: jax.Array, V: jax.Array,
@@ -191,11 +200,13 @@ def _sharded_product(gl, q, w, V, mesh: Mesh, total_leaves: int,
                                        num_segments=total_leaves)
 
         # tree 0 seeds the carry, so it has the per-shard type of the body
-        s = jax.lax.fori_loop(1, gl_s.shape[1],
-                              lambda t, s: s + tree(t), tree(0))
-        s = jax.lax.psum(s, data_axis)                     # (L, C)
-        out = (q_s[:, :, None] * s[gl_s]).sum(axis=1)      # (n/dp, C)
-        return jax.lax.psum(out, model_axis)
+        with jax.named_scope("bucket"):
+            s = jax.lax.fori_loop(1, gl_s.shape[1],
+                                  lambda t, s: s + tree(t), tree(0))
+            s = jax.lax.psum(s, data_axis)                 # (L, C)
+        with jax.named_scope("gather"):
+            out = (q_s[:, :, None] * s[gl_s]).sum(axis=1)  # (n/dp, C)
+            return jax.lax.psum(out, model_axis)
 
     spec_nt = P(data_axis, model_axis)
     spec_nc = P(data_axis, None)

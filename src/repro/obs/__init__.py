@@ -9,8 +9,11 @@ Three dependency-free pillars shared by serving, the engine, and training:
     Prometheus text exposition, plus the shared :class:`EWMA` primitive.
 
 ``obs.trace``
-    Per-request span trees on an injectable clock, sampled into a bounded
-    ring buffer, exportable as Chrome ``chrome://tracing`` JSON.
+    ``span(name, **stats)`` — in-process phases of one thread as
+    ``repro:<name>`` spans on the JAX profiler's clock (the engine's
+    routing, upload, dispatch and fetch); and per-request span trees on an
+    injectable clock, sampled into a bounded ring buffer, exportable as
+    Chrome ``chrome://tracing`` JSON.
 
 ``obs.profile``
     ``instrument(engine)`` — a transparent proxy timing every
@@ -26,9 +29,9 @@ from .http import EXPOSITION_CONTENT_TYPE, MetricsHTTPServer
 from .metrics import (EWMA, Counter, Gauge, Histogram, MetricsRegistry,
                       global_registry, parse_exposition)
 from .profile import InstrumentedEngine, instrument
-from .trace import NULL_SPAN, Span, Tracer
+from .trace import NULL_SPAN, Span, Tracer, span
 
 __all__ = ["MetricsRegistry", "Counter", "Gauge", "Histogram", "EWMA",
            "global_registry", "parse_exposition", "Tracer", "Span",
-           "NULL_SPAN", "instrument", "InstrumentedEngine",
+           "NULL_SPAN", "span", "instrument", "InstrumentedEngine",
            "MetricsHTTPServer", "EXPOSITION_CONTENT_TYPE"]

@@ -1,4 +1,17 @@
-"""Per-request span trees with Chrome-trace export.
+"""Spans: in-process phases on the profiler's clock, and per-request
+span trees with Chrome-trace export.
+
+Two kinds, for two jobs:
+
+- :func:`span` is for the in-process phases of one thread (the engine's
+  routing, upload, dispatch and fetch): a ``jax.profiler.TraceAnnotation``
+  named ``repro:<name>``, so it lands on the same clock as the device's
+  operations in a ``jax.profiler`` trace, with its keyword ``stats`` (a
+  byte count, say) on the event.  Always on: with no profiler session it
+  costs about a microsecond, and without JAX installed it is a no-op.
+- :class:`Tracer` is for request trees that cross threads (serving's
+  submit → admit → tier → engine op), on an injectable clock that the
+  serving deadline logic shares.
 
 A :class:`Tracer` hands out root :class:`Span` objects (one per served
 request); spans nest (``span.child``), carry point events
@@ -22,6 +35,8 @@ microseconds.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import itertools
 import json
 import threading
@@ -29,7 +44,31 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-__all__ = ["Span", "Tracer", "NULL_SPAN"]
+__all__ = ["Span", "Tracer", "NULL_SPAN", "span", "SPAN_PREFIX"]
+
+SPAN_PREFIX = "repro:"       # name prefix of every :func:`span`
+
+
+@functools.lru_cache(maxsize=None)
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, or None where JAX is not
+    installed; looked up once, on the first :func:`span`."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
+def span(name: str, **stats):
+    """Context manager: a ``repro:<name>`` span on the profiler's clock,
+    carrying ``stats`` (ints, floats or strings) on its trace event.  JAX is
+    imported on first use, so this module stays importable without it;
+    without JAX the span is a no-op."""
+    ann = _annotation()
+    if ann is None:
+        return contextlib.nullcontext()
+    return ann(SPAN_PREFIX + name, **stats)
 
 
 class _NullSpan:

@@ -26,9 +26,12 @@ Backends
 
 Serving note: the bucket table S = Wᵀ V of every factored product depends
 only on the reference side, so for narrow V it is LRU-cached by V content
-(scipy + native paths).  A serving loop calling ``predict(X=batch)`` every
-tick with the same labels pays the O(N T C) bucket once and only the
-O(n_batch T C) query-side gather per tick.
+on every backend: a host array on scipy and native, a device array on jax
+and pallas (built there by ``jax_ops.swlc_bucket`` and never copied back).
+A serving loop calling ``predict(X=batch)`` every tick with the same
+labels pays the O(N T C) bucket once and only the O(n_batch T C)
+query-side gather per tick; on jax / pallas it uploads only the batch's
+(gl, q).
 
 No path in this module iterates over trees in Python.
 """
@@ -213,6 +216,8 @@ class ProximityEngine:
         self._qs_lock = threading.Lock() if oos_lock is None else oos_lock
         self.qs_cache_hits = 0
         self.qs_cache_misses = 0
+        self.ref_cache_hits = 0
+        self.ref_cache_misses = 0
         self._use_x64 = self.dtype == np.float64
         # dtype of the dense block ops (kernel_block, topk, OOS squared row
         # sums) on pallas: the compiled block_prox kernel is float32-only,
@@ -224,12 +229,14 @@ class ProximityEngine:
         self._train_row_sums: Optional[np.ndarray] = None
         self.last_matmat_path: Optional[str] = None   # 'sharded' | 'segment'
         # reference bucket tables S = Wᵀ V (serving), LRU of key ->
-        # (keepalive V | None, S).  Sized above the number of distinct
-        # fixed tables a mixed serving tick touches (labels, ones,
-        # propagation field, Nyström basis, per-class masks) so rotating
-        # inserts from iterative solvers cannot thrash the hot entries;
-        # additionally bounded in bytes so huge-L engines cannot pin
-        # hundreds of MB of dead tables.
+        # (keepalive V | None, S); S is a device array on jax / pallas.
+        # Sized above the number of distinct fixed tables a mixed serving
+        # tick touches (labels, ones, propagation field, Nyström basis,
+        # per-class masks) so rotating inserts from iterative solvers
+        # cannot thrash the hot entries; additionally bounded in bytes so
+        # huge-L engines cannot pin hundreds of MB of dead tables, on the
+        # host or on the device.  ref_cache_hits / ref_cache_misses count
+        # the lookups of cacheable V.
         self._ref_cache: "OrderedDict[object, tuple]" = OrderedDict()
         self._ref_cache_size = ref_cache_size
         self._ref_cache_bytes = 0
@@ -334,17 +341,20 @@ class ProximityEngine:
                          ref_key=None) -> np.ndarray:
         """Backend dispatch for (P V) on an already-resolved query state."""
         _count_path("matmat", self.backend)
+        if self.backend in _DEVICE_BACKENDS:
+            return self._segment_matmat(qs, V, ref_key)
+        S = self._ref_table(V, key=ref_key)
         if self.backend == "scipy":
-            return np.asarray(qs.Q @ self._ref_table(V, key=ref_key))
-        if self.backend == "native":
-            out = _native.prox_gather_native(qs.gl, qs.q,
-                                             self._ref_table(V, key=ref_key))
-            return out.astype(self.dtype, copy=False)
-        return self._segment_matmat(qs, V)
+            return np.asarray(qs.Q @ S)
+        out = _native.prox_gather_native(qs.gl, qs.q, S)
+        return out.astype(self.dtype, copy=False)
 
-    def _ref_table(self, V: np.ndarray, key=None) -> np.ndarray:
+    def _ref_table(self, V: np.ndarray, key=None):
         """Reference bucket table S = Wᵀ V of the factored product
-        P V = Q (Wᵀ V) — the half that does not depend on the query rows.
+        P V = Q (Wᵀ V) — the half that does not depend on the query rows:
+        a host array on scipy / native; on jax / pallas a device array of
+        (total_leaves + 1) rows (``jax_ops.swlc_bucket``), which stays on
+        the device, so a cached table costs a call no transfer at all.
 
         Narrow V (≤ 32 columns: labels, class scores, Nyström bases) is
         LRU-cached, so a serving loop re-applying the same V every tick pays
@@ -357,7 +367,9 @@ class ProximityEngine:
         changes every call just rotate through the LRU without hashing).
         Cached arrays are treated as immutable; mutate a cached V in place
         and you get the stale table.  Wide V bypasses the cache (an (L, C)
-        table would dwarf the factors), and total cached bytes are bounded.
+        table would dwarf the factors), and total cached bytes, host or
+        device, are bounded.  Each lookup counts as ``hit``, ``miss`` or
+        ``uncached`` in ``engine_ref_table_total{backend,result}``.
         """
         keepalive = None
         if key is False:        # budget-chunked slice: never worth caching
@@ -369,20 +381,50 @@ class ProximityEngine:
             hit = self._ref_cache.get(key)
             if hit is not None:
                 self._ref_cache.move_to_end(key)
+                self.ref_cache_hits += 1
+                self._count_ref_table("hit")
                 return hit[1]
-        if self.backend == "native":
-            S = _native.prox_bucket_native(self.gl, self.w, V,
-                                           self.total_leaves)
-        else:
-            S = np.asarray(self.W.T @ V)
-        if key is not None:
-            self._ref_cache[key] = (keepalive, S)
-            self._ref_cache_bytes += S.nbytes
-            while len(self._ref_cache) > self._ref_cache_size or \
-                    self._ref_cache_bytes > self._ref_cache_byte_budget:
-                _, (_, old) = self._ref_cache.popitem(last=False)
-                self._ref_cache_bytes -= old.nbytes
+        with span("engine.ref_table"):
+            S = self._build_ref_table(V)
+        if key is None:
+            self._count_ref_table("uncached")
+            return S
+        self.ref_cache_misses += 1
+        self._count_ref_table("miss")
+        self._ref_cache[key] = (keepalive, S)
+        self._ref_cache_bytes += S.nbytes
+        while len(self._ref_cache) > self._ref_cache_size or \
+                self._ref_cache_bytes > self._ref_cache_byte_budget:
+            _, (_, old) = self._ref_cache.popitem(last=False)
+            self._ref_cache_bytes -= old.nbytes
         return S
+
+    def _build_ref_table(self, V: np.ndarray):
+        if self.backend == "native":
+            return _native.prox_bucket_native(self.gl, self.w, V,
+                                              self.total_leaves)
+        if self.backend == "scipy":
+            return np.asarray(self.W.T @ V)
+        from . import jax_ops
+        with _x64_scope(self._use_x64):
+            # one tree per step: leaf ids are tree-major, so each step
+            # scatters N_ref rows into its own tree's buckets.  A single
+            # scatter of all N_ref·T rows costs the TPU compiler minutes.
+            staged = _stage(self.gl, self.w, V)
+            with span("engine.dispatch"):
+                S = jax_ops.swlc_bucket(*staged, self.total_leaves,
+                                        t_chunk=1)
+            # drop the inputs now: only S stays on the device
+            del staged
+        return S
+
+    def _count_ref_table(self, result: str) -> None:
+        from ..obs.metrics import global_registry
+        global_registry().counter(
+            "engine_ref_table_total",
+            "reference bucket table lookups by result",
+            labels=("backend", "result")).labels(
+            backend=self.backend, result=result).inc()
 
     def row_sums(self, X: Optional[np.ndarray] = None) -> np.ndarray:
         """Kernel row sums Σ_j P(i,j) = P·1 through the factors (the degree
@@ -398,7 +440,8 @@ class ProximityEngine:
             self._train_row_sums = out
         return out
 
-    def _segment_matmat(self, qs: QueryState, V: np.ndarray) -> np.ndarray:
+    def _segment_matmat(self, qs: QueryState, V: np.ndarray,
+                        ref_key=None) -> np.ndarray:
         from . import jax_ops
         n_ref, T = self.gl.shape
         with _x64_scope(self._use_x64):
@@ -429,18 +472,15 @@ class ProximityEngine:
                         parts += _fetch(out)
                     self.last_matmat_path = "sharded"
                     return np.concatenate(parts, axis=1)[:n_ref]
-            # one tree per step: leaf ids are tree-major, so each step
-            # scatters N_ref rows into its own tree's buckets.  A single
-            # scatter of all N_ref·T rows costs the TPU compiler minutes.
-            staged = _stage(qs.gl, qs.q, self.gl, self.w, V)
+            S = self._ref_table(V, key=ref_key)
+            staged = _stage(qs.gl, qs.q)
             with span("engine.dispatch"):
-                out = jax_ops.swlc_predict(*staged, self.total_leaves,
-                                           t_chunk=1)
+                out = jax_ops.swlc_gather(*staged, S, t_chunk=1)
             # drop the inputs now, as inline arguments would be: their
-            # device buffers then go as soon as the product has read them.
+            # device buffers then go as soon as the gather has read them.
             # Held through the _fetch below, they stalled about 1 call in
             # 70 by 1-2 s on a TPU v5e (cause not known)
-            del staged
+            del staged, S
             self.last_matmat_path = "segment"
             return _fetch(out)[0]
 

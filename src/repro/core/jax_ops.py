@@ -7,6 +7,9 @@ On TPU we avoid CSR scatter/gather entirely.  The factored kernel apply
   2. gather:  (Pv)[i] = Σ_t q[i,t] · s[gl[i,t]]
 
 Both are O(N·T) with no data-dependent shapes, so they jit/pjit cleanly.
+They are separate programs (``swlc_bucket``, ``swlc_gather``): the table
+s = Wᵀ v depends only on the reference side, so a caller that applies one
+v to many query batches builds it once and keeps it on the device.
 Each stage runs under a ``jax.named_scope`` of its name (``bucket``,
 ``gather``), which the compiled HLO carries in its ``op_name`` metadata,
 so a device trace can tell the two stages' operations apart.
@@ -25,8 +28,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["swlc_matvec", "swlc_matmat", "swlc_block", "swlc_predict",
-           "swlc_topk", "sharded_swlc_matmat", "default_mesh"]
+__all__ = ["swlc_matvec", "swlc_matmat", "swlc_bucket", "swlc_gather",
+           "swlc_block", "swlc_predict", "swlc_topk", "sharded_swlc_matmat",
+           "default_mesh"]
 
 
 def default_mesh(data_axis: str = "data",
@@ -61,65 +65,91 @@ def swlc_matvec(gl: jax.Array, q: jax.Array, w: jax.Array, v: jax.Array,
     return (q * s[gl]).sum(axis=1)
 
 
+def _pad_trees(gl: jax.Array, x: jax.Array, total_leaves: int,
+               t_chunk: int) -> Tuple[jax.Array, jax.Array, int]:
+    """(gl, x, n_chunks) with the tree columns padded to a multiple of
+    ``t_chunk``: sentinel columns of leaf id ``total_leaves`` (a dedicated
+    padding bucket) and weight 0, which contribute nothing on either side."""
+    pad = (-gl.shape[1]) % t_chunk
+    if pad:
+        gl = jnp.pad(gl, ((0, 0), (0, pad)), constant_values=total_leaves)
+        x = jnp.pad(x, ((0, 0), (0, pad)))
+    return gl, x, gl.shape[1] // t_chunk
+
+
+def _tree_chunk(a: jax.Array, c, t_chunk: int) -> jax.Array:
+    return jax.lax.dynamic_slice_in_dim(a, c * t_chunk, t_chunk, axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("total_leaves", "t_chunk"))
+def swlc_bucket(gl_w: jax.Array, w: jax.Array, V: jax.Array,
+                total_leaves: int,
+                t_chunk: Optional[int] = None) -> jax.Array:
+    """Reference bucket table S = Wᵀ V of reference rows (gl_w, w):
+    (total_leaves + 1, C), the last row the (zero) padding bucket.
+
+    It depends only on the reference side, so a caller applying the same V
+    to many query batches builds it once (``ProximityEngine._ref_table``).
+    ``t_chunk`` accumulates over tree chunks of that size, so the dense
+    intermediate is (N_w, t_chunk, C) and each scatter has N_w·t_chunk
+    rows, which is what the TPU compiler's time grows with: the engine
+    passes 1.
+    """
+    nw, T = gl_w.shape
+    C = V.shape[1]
+    with jax.named_scope("bucket"):
+        if t_chunk is None or t_chunk >= T:
+            contrib = w[:, :, None] * V[:, None, :]          # (N_w, T, C)
+            return jax.ops.segment_sum(contrib.reshape(nw * T, -1),
+                                       gl_w.ravel(),
+                                       num_segments=total_leaves + 1)
+        gl_w, w, n_chunks = _pad_trees(gl_w, w, total_leaves, t_chunk)
+
+        def bucket(c, s):
+            contrib = _tree_chunk(w, c, t_chunk)[:, :, None] * \
+                V[:, None, :]                            # (N_w, t_chunk, C)
+            return s + jax.ops.segment_sum(
+                contrib.reshape(nw * t_chunk, -1),
+                _tree_chunk(gl_w, c, t_chunk).ravel(),
+                num_segments=total_leaves + 1)
+
+        return jax.lax.fori_loop(
+            0, n_chunks, bucket,
+            jnp.zeros((total_leaves + 1, C),
+                      dtype=jnp.result_type(w.dtype, V.dtype)))
+
+
+@functools.partial(jax.jit, static_argnames=("t_chunk",))
+def swlc_gather(gl_q: jax.Array, q: jax.Array, S: jax.Array,
+                t_chunk: Optional[int] = None) -> jax.Array:
+    """(P V)[i] = Σ_t q[i,t] · S[gl_q[i,t]] for query rows (gl_q, q) and a
+    bucket table S of ``swlc_bucket``: (N_q, C).  ``t_chunk`` as there."""
+    nq, T = gl_q.shape
+    with jax.named_scope("gather"):
+        if t_chunk is None or t_chunk >= T:
+            return (q[:, :, None] * S[gl_q]).sum(axis=1)
+        gl_q, q, n_chunks = _pad_trees(gl_q, q, S.shape[0] - 1, t_chunk)
+
+        def gather(c, out):
+            qq = _tree_chunk(q, c, t_chunk)
+            return out + (qq[:, :, None] *
+                          S[_tree_chunk(gl_q, c, t_chunk)]).sum(axis=1)
+
+        return jax.lax.fori_loop(
+            0, n_chunks, gather,
+            jnp.zeros((nq, S.shape[1]),
+                      dtype=jnp.result_type(q.dtype, S.dtype)))
+
+
 @functools.partial(jax.jit, static_argnames=("total_leaves", "t_chunk"))
 def _swlc_product(gl_q: jax.Array, q: jax.Array, gl_w: jax.Array,
                   w: jax.Array, V: jax.Array, total_leaves: int,
                   t_chunk: Optional[int]) -> jax.Array:
     """(P V) for P = SWLC(q, w) with query rows (gl_q, q) and reference rows
-    (gl_w, w); V: (N_w, C).
-
-    ``t_chunk`` bounds the dense collision intermediate: instead of one
-    (N, T, C) tensor, both the bucket and gather stages accumulate over tree
-    chunks of size t_chunk, so peak memory is (N, t_chunk, C).  It also
-    bounds the rows of each scatter, which is what the TPU compiler's time
-    grows with: the engine passes 1.
-    """
-    nq, T = gl_q.shape
-    nw = gl_w.shape[0]
-    C = V.shape[1]
-    out_dtype = jnp.result_type(q.dtype, V.dtype)
-    if t_chunk is None or t_chunk >= T:
-        with jax.named_scope("bucket"):
-            contrib = w[:, :, None] * V[:, None, :]          # (N_w, T, C)
-            s = jax.ops.segment_sum(contrib.reshape(nw * T, -1),
-                                    gl_w.ravel(),
-                                    num_segments=total_leaves)   # (L, C)
-        with jax.named_scope("gather"):
-            return (q[:, :, None] * s[gl_q]).sum(axis=1)
-
-    pad = (-T) % t_chunk
-    if pad:
-        # sentinel tree columns: leaf id = total_leaves (a dedicated padding
-        # bucket), weights 0 — contribute nothing on either side
-        gl_q = jnp.pad(gl_q, ((0, 0), (0, pad)), constant_values=total_leaves)
-        gl_w = jnp.pad(gl_w, ((0, 0), (0, pad)), constant_values=total_leaves)
-        q = jnp.pad(q, ((0, 0), (0, pad)))
-        w = jnp.pad(w, ((0, 0), (0, pad)))
-    n_chunks = (T + pad) // t_chunk
-
-    def bucket(c, s):
-        sl = jax.lax.dynamic_slice_in_dim
-        gw = sl(gl_w, c * t_chunk, t_chunk, axis=1)
-        ww = sl(w, c * t_chunk, t_chunk, axis=1)
-        contrib = ww[:, :, None] * V[:, None, :]         # (N_w, t_chunk, C)
-        return s + jax.ops.segment_sum(
-            contrib.reshape(nw * t_chunk, -1), gw.ravel(),
-            num_segments=total_leaves + 1)
-
-    with jax.named_scope("bucket"):
-        s = jax.lax.fori_loop(0, n_chunks, bucket,
-                              jnp.zeros((total_leaves + 1, C),
-                                        dtype=out_dtype))
-
-    def gather(c, out):
-        sl = jax.lax.dynamic_slice_in_dim
-        gq = sl(gl_q, c * t_chunk, t_chunk, axis=1)
-        qq = sl(q, c * t_chunk, t_chunk, axis=1)
-        return out + (qq[:, :, None] * s[gq]).sum(axis=1)
-
-    with jax.named_scope("gather"):
-        return jax.lax.fori_loop(0, n_chunks, gather,
-                                 jnp.zeros((nq, C), dtype=out_dtype))
+    (gl_w, w); V: (N_w, C): the bucket stage, then the gather, in one
+    program."""
+    return swlc_gather(gl_q, q, swlc_bucket(gl_w, w, V, total_leaves,
+                                            t_chunk), t_chunk)
 
 
 def swlc_matmat(gl: jax.Array, q: jax.Array, w: jax.Array, V: jax.Array,

@@ -293,3 +293,168 @@ def test_engine_sharded_matmat_multi_device():
                        text=True, env=env, timeout=600)
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-3000:]}"
     assert "SHARDED ENGINE OK" in r.stdout
+
+
+# ------------------------------------------ reference bucket tables (cache) --
+def _ref_counts(backend):
+    from repro.obs.metrics import global_registry
+    fam = global_registry().counter("engine_ref_table_total",
+                                    labels=("backend", "result"))
+    return {r: fam.labels(backend=backend, result=r).value
+            for r in ("hit", "miss", "uncached")}
+
+
+def _delta(before, after):
+    return {k: after[k] - before[k] for k in before}
+
+
+def _count_buckets(monkeypatch):
+    """Calls of ``jax_ops.swlc_bucket``, the device bucket stage."""
+    from repro.core import jax_ops
+    calls = []
+    real = jax_ops.swlc_bucket
+
+    def counted(*a, **k):
+        calls.append(a[2].shape)
+        return real(*a, **k)
+    monkeypatch.setattr(jax_ops, "swlc_bucket", counted)
+    return calls
+
+
+def _fused_predict(eng, y, C, X=None):
+    """The single fused program of both stages (``swlc_predict``), with
+    the engine's own inputs and the self-term removed for X=None."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import jax_ops
+    qs = eng.query_state(X)
+    Y, _ = eng._label_table(y, C)
+    with jax.enable_x64(True):
+        out = np.asarray(jax_ops.swlc_predict(
+            *(jnp.asarray(a) for a in (qs.gl, qs.q, eng.gl, eng.w, Y)),
+            eng.total_leaves, t_chunk=1))
+    if X is None:
+        out = out - (qs.q * eng.w).sum(axis=1)[:, None] * Y
+    return out
+
+
+@pytest.mark.parametrize("oos", [False, True])
+@pytest.mark.parametrize("backend", ["jax", "pallas"])
+def test_device_ref_table_predict_is_the_fused_product(rf_kernel_cache,
+                                                       backend, oos):
+    """With S built once and kept on the device, predict gives the fused
+    bucket-and-gather program bit for bit, cold and warm, and scipy to
+    1e-12 (OOS, and the training set with ``exclude_self``)."""
+    fk = rf_kernel_cache["gap"]
+    X, y = rf_kernel_cache["_data"]
+    C = fk.forest.n_classes_
+    Xq = X[:40] + 2e-3 if oos else None
+    eng = ProximityEngine(fk.ctx, fk.assignment, forest=fk.forest,
+                          backend=backend)
+    want = _fused_predict(eng, y, C, Xq)
+    cold = eng.predict(y, n_classes=C, X=Xq)
+    warm = eng.predict(y, n_classes=C, X=Xq)
+    np.testing.assert_array_equal(cold, want)
+    np.testing.assert_array_equal(warm, want)
+    ref = fk.engine.predict(y, n_classes=C, X=Xq)
+    np.testing.assert_allclose(warm, ref, rtol=0, atol=1e-12)
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_second_predict_hits_the_ref_table(rf_kernel_cache, backend,
+                                           monkeypatch):
+    """The same labels a second time: one ``hit``, and on a device backend
+    no bucket stage is dispatched; the table is a device array there."""
+    import jax
+    fk = rf_kernel_cache["original"]
+    X, y = rf_kernel_cache["_data"]
+    C = fk.forest.n_classes_
+    eng = ProximityEngine(fk.ctx, fk.assignment, forest=fk.forest,
+                          backend=backend)
+    buckets = _count_buckets(monkeypatch)
+    before = _ref_counts(backend)
+    first = eng.predict(y, n_classes=C, X=X[:20] + 1e-3)
+    mid = _ref_counts(backend)
+    second = eng.predict(y, n_classes=C, X=X[20:50] + 1e-3)
+    assert _delta(before, mid) == {"hit": 0, "miss": 1, "uncached": 0}
+    assert _delta(mid, _ref_counts(backend)) == \
+        {"hit": 1, "miss": 0, "uncached": 0}
+    assert (eng.ref_cache_hits, eng.ref_cache_misses) == (1, 1)
+    (_, S), = eng._ref_cache.values()
+    on_device = backend in ("jax", "pallas")
+    assert isinstance(S, jax.Array) == on_device
+    assert buckets == ([(len(y), C)] if on_device else [])
+    np.testing.assert_allclose(
+        np.concatenate([first, second]),
+        fk.engine.predict(y, n_classes=C,
+                          X=np.concatenate([X[:20], X[20:50]]) + 1e-3),
+        atol=1e-12)
+
+
+@pytest.mark.parametrize("how", ["wide", "budget_chunked"])
+@pytest.mark.parametrize("backend", ["jax", "pallas"])
+def test_uncached_ref_tables_stay_correct(rf_kernel_cache, backend, how,
+                                          monkeypatch):
+    """Wide V (33 columns) and the column chunks of a memory budget are
+    built once per call and never cached; answers match scipy."""
+    fk = rf_kernel_cache["gap"]
+    eng = ProximityEngine(fk.ctx, fk.assignment, forest=fk.forest,
+                          backend=backend)
+    rng = np.random.default_rng(8)
+    if how == "wide":
+        V, chunks = rng.normal(size=(eng.W.shape[0], 33)), 1
+    else:
+        V, chunks = rng.normal(size=(eng.W.shape[0], 3)), 3
+        monkeypatch.setattr(eng, "_col_chunk", lambda n_cols: 1)
+    buckets = _count_buckets(monkeypatch)
+    before = _ref_counts(backend)
+    for _ in range(2):
+        np.testing.assert_allclose(eng.matmat(V), fk.engine.matmat(V),
+                                   atol=1e-12)
+    assert _delta(before, _ref_counts(backend)) == \
+        {"hit": 0, "miss": 0, "uncached": 2 * chunks}
+    assert len(buckets) == 2 * chunks
+    assert not eng._ref_cache and eng._ref_cache_bytes == 0
+
+
+def test_byte_budget_evicts_device_ref_tables(rf_kernel_cache):
+    """Device tables count their bytes against the cache's byte budget:
+    room for one table keeps only the newest."""
+    fk = rf_kernel_cache["original"]
+    X, y = rf_kernel_cache["_data"]
+    C = fk.forest.n_classes_
+    eng = ProximityEngine(fk.ctx, fk.assignment, forest=fk.forest,
+                          backend="jax")
+    table = (eng.total_leaves + 1) * C * 8
+    eng._ref_cache_byte_budget = table + table // 2
+    y2 = np.roll(y, 1)
+    Xq = X[:10] + 1e-3
+    eng.predict(y, n_classes=C, X=Xq)
+    eng.predict(y2, n_classes=C, X=Xq)
+    assert len(eng._ref_cache) == 1 and eng._ref_cache_bytes == table
+    (key, (_, S)), = eng._ref_cache.items()
+    assert key == eng._label_table(y2, C)[1] and S.nbytes == table
+    # the evicted table is built again, and the answer is unchanged
+    got = eng.predict(y, n_classes=C, X=Xq)
+    assert (eng.ref_cache_hits, eng.ref_cache_misses) == (0, 3)
+    np.testing.assert_allclose(got, fk.engine.predict(y, n_classes=C, X=Xq),
+                               atol=1e-12)
+
+
+def test_two_label_arrays_get_two_device_tables(rf_kernel_cache):
+    fk = rf_kernel_cache["gap"]
+    X, y = rf_kernel_cache["_data"]
+    C = fk.forest.n_classes_
+    eng = ProximityEngine(fk.ctx, fk.assignment, forest=fk.forest,
+                          backend="pallas")
+    y2 = (np.asarray(y) + 1) % C
+    Xq = X[:30] + 1e-3
+    got = [eng.predict(lab, n_classes=C, X=Xq) for lab in (y, y2, y, y2)]
+    assert len(eng._ref_cache) == 2
+    assert (eng.ref_cache_hits, eng.ref_cache_misses) == (2, 2)
+    a, b = (np.asarray(S) for _, S in eng._ref_cache.values())
+    assert a.shape == b.shape == (eng.total_leaves + 1, C)
+    assert not np.array_equal(a, b)
+    for lab, out in zip((y, y2, y, y2), got):
+        np.testing.assert_allclose(
+            out, fk.engine.predict(lab, n_classes=C, X=Xq), atol=1e-12)

@@ -9,7 +9,9 @@ import jax.numpy as jnp
 from _hyp import given, settings, st   # hypothesis, or deterministic fallback
 
 from repro.core.factorization import naive_swlc
-from repro.core.jax_ops import swlc_block, swlc_matmat, swlc_matvec, swlc_predict
+from repro.core.jax_ops import (_swlc_product, swlc_block, swlc_bucket,
+                                swlc_gather, swlc_matmat, swlc_matvec,
+                                swlc_predict)
 from repro.core.spectral import LeafPCA, kernel_eigs
 
 
@@ -97,6 +99,31 @@ def test_swlc_predict_oos():
     got = swlc_predict(jnp.asarray(gl_q), jnp.asarray(q), jnp.asarray(gl_w),
                        jnp.asarray(w), jnp.asarray(Y), T * lpt)
     np.testing.assert_allclose(np.asarray(got), P @ Y, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+@pytest.mark.parametrize("t_chunk", [None, 1, 3])
+def test_bucket_then_gather_is_the_fused_product(t_chunk, dtype):
+    """The two stages as separate programs give the fused product bit for
+    bit (T = 8 is no multiple of 3: the padding bucket is used)."""
+    rng = np.random.default_rng(5)
+    n, nq, T, lpt = 70, 11, 8, 5
+    L = T * lpt
+    gl_w, gl_q = _leafset(rng, n, T, lpt), _leafset(rng, nq, T, lpt)
+    q, w = rng.random((nq, T)), rng.random((n, T))
+    Y = rng.random((n, 3))
+    with jax.enable_x64(dtype == np.float64):
+        a = [jnp.asarray(x, dtype) for x in (q, w, Y)]
+        S = swlc_bucket(jnp.asarray(gl_w), a[1], a[2], L, t_chunk)
+        got = np.asarray(swlc_gather(jnp.asarray(gl_q), a[0], S, t_chunk))
+        want = np.asarray(_swlc_product(jnp.asarray(gl_q), a[0],
+                                        jnp.asarray(gl_w), a[1], a[2], L,
+                                        t_chunk))
+    assert S.shape == (L + 1, 3) and got.dtype == dtype
+    assert not np.asarray(S)[L].any()
+    np.testing.assert_array_equal(got, want)
+    P = naive_swlc(gl_q, gl_w, q, w)
+    np.testing.assert_allclose(got, P @ Y, rtol=2e-4, atol=2e-4)
 
 
 # ------------------------------------------------------------------- spectral

@@ -2,8 +2,12 @@
 
 Everything that belongs to one cell is found by name: the cell in
 ``BENCHMARK.json`` names its configuration (``bench/configs/<name>.json``)
-and its traffic mix (``bench/traffic/<traffic>.json``); the mix names its
-operation (``bench/ops/<op>.py``, class ``Op``) and its loop
+and its traffic mix (``bench/traffic/<traffic>.json``); the configuration
+names its data generator (``bench/generators/<generator>.py``, see
+``data``), the keyword arguments of its ``ForestKernel`` (``forest``) and,
+by its ``kernel_method``, the reference's weights
+(``bench/weights/<kernel_method>.py``, see ``reference``); the mix names
+its operation (``bench/ops/<op>.py``, class ``Op``) and its loop
 (``bench/loops/<loop>.py``: ``prepare``, ``run``, ``end_to_end``); each
 per-layer metric is read by ``bench/metrics/<metric>.py`` (``read``).
 This file keeps set-up, the check and the report.
@@ -12,7 +16,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import importlib.util
 import json
 import os
 import shutil
@@ -25,6 +28,7 @@ from typing import Callable, List, Optional
 import numpy as np
 
 from . import data, trace
+from .lookup import load
 from .reference import ReferenceForest
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -65,16 +69,6 @@ def load_cell(name: str, spec: dict, sizes: Optional[dict] = None) -> tuple:
 def metrics_for(spec: dict, cell: str, kind: str) -> List[dict]:
     """The cell's ``end_to_end`` or ``per_layer`` metrics."""
     return [m for m in spec[kind] if cell in m.get("workloads", [cell])]
-
-
-def load(kind: str, name: str):
-    """The module ``bench/<kind>/<name>.py``."""
-    path = BENCH / kind / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{kind}_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # ------------------------------------------------------------ the chip --
@@ -131,20 +125,20 @@ class Setup:
 
 
 def fit(cfg: dict) -> Setup:
-    """The configuration's training rows, host fit and factorization."""
+    """The configuration's training rows, host fit and factorization: every
+    key of its ``forest`` is a ``ForestKernel`` field, passed as it is
+    (``dtype`` by name); the seed comes from ``model_seed``."""
     from repro.core.api import ForestKernel
+    f = dict(cfg["forest"])
+    fields = {k.name for k in dataclasses.fields(ForestKernel)}
+    unknown = sorted(set(f) - fields)
+    if unknown:
+        raise ValueError(f"forest keys {unknown} of configuration "
+                         f"{cfg.get('name')!r} are not ForestKernel fields")
+    if "dtype" in f:
+        f["dtype"] = np.dtype(f["dtype"]).type
     X, y = data.training_rows(cfg)
-    f = cfg["forest"]
-    fk = ForestKernel(model_type=f["model_type"],
-                      kernel_method=f["kernel_method"],
-                      n_trees=f["n_trees"], max_depth=f["max_depth"],
-                      min_samples_leaf=f["min_samples_leaf"],
-                      max_features=f["max_features"], n_bins=f["n_bins"],
-                      seed=data.forest_seed(cfg),
-                      dtype=np.dtype(f["dtype"]).type,
-                      engine_backend=f["engine_backend"],
-                      routing_backend=f["routing_backend"],
-                      tree_backend=f["tree_backend"],
+    fk = ForestKernel(**f, seed=data.forest_seed(cfg),
                       n_jobs=os.cpu_count() or 1)
     fk.fit(X, y)
     return Setup(cfg=cfg, kernel=fk, X_train=X, y_train=y)
@@ -153,7 +147,8 @@ def fit(cfg: dict) -> Setup:
 def reference(s: Setup) -> ReferenceForest:
     forest = s.kernel.forest
     return ReferenceForest(forest.trees_, forest.inbag_, s.X_train,
-                           s.y_train, s.cfg["forest"]["kernel_method"])
+                           s.y_train, s.cfg["forest"]["kernel_method"],
+                           tree_weights=forest.tree_weights_)
 
 
 # ----------------------------------------------------------- a window --

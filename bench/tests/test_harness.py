@@ -1,13 +1,18 @@
 """Whole runs of the harness with the CPU standing in for the chip, the
 control, the faults that ``correct`` has to catch, and the lookup of a
 cell's files by name."""
+import filecmp
 import json
+import os
 import shutil
+import subprocess
+import sys
 
 import numpy as np
 import pytest
 
-from bench import harness
+from bench import data, harness, lookup
+from bench.reference import ReferenceForest
 
 CELLS = ["covertype.oos-predict", "higgs.oos-predict"]
 
@@ -192,6 +197,7 @@ def test_cell_added_as_files_alone(jax_cpu, tmp_path, monkeypatch, traced):
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
     monkeypatch.setattr(harness, "ROOT", root)
     monkeypatch.setattr(harness, "BENCH", root / "bench")
+    monkeypatch.setattr(lookup, "BENCH", root / "bench")
     res = harness.run("higgs.fixed-mass", 5, 0.3, traced, 0.0,
                       devices=lambda c: jax_cpu.devices()[:c],
                       sizes={"forest": {"n_trees": 8}})
@@ -201,3 +207,114 @@ def test_cell_added_as_files_alone(jax_cpu, tmp_path, monkeypatch, traced):
         assert res["metrics"]["rows_per_call"]["value"] == 16
     else:
         assert set(res["metrics"]) == {"calls_per_s", "setup_s"}
+
+
+GENERATOR = """
+\"\"\"Two Gaussian classes; labels by a draw of their own.\"\"\"
+import numpy as np
+
+from bench.data import seed_rng
+
+
+def world(cfg):
+    rng = seed_rng(cfg["model_seed"], "world")
+    return {"mean": rng.normal(0.0, 1.0, size=(2, cfg["n_features"]))}
+
+
+def labels(cfg, n, rng):
+    return (rng.random(n) < cfg["signal_share"]).astype(np.int64)
+
+
+def rows(cfg, world, y, rng):
+    return world["mean"][y] + rng.normal(size=(len(y), cfg["n_features"]))
+"""
+
+BOOSTED = """
+\"\"\"Boosted proximity (Tan et al. 2020): q_t = w_t = sqrt(w_t / sum_s w_s).\"\"\"
+import numpy as np
+
+
+def reference_weights(model, t, leaf):
+    tw = model.tree_weights
+    return np.full(len(leaf), np.sqrt(tw[t] / tw.sum()))
+
+
+query_weights = reference_weights
+"""
+
+GBT = {"name": "toy-gbt", "model_seed": 3, "generator": "toy",
+       "n_train": 1200, "n_features": 6, "n_classes": 2, "signal_share": 0.4,
+       "forest": {"model_type": "gbt", "task": "classification",
+                  "kernel_method": "boosted", "n_trees": 8, "max_depth": 3,
+                  "min_samples_leaf": 1, "max_features": None, "n_bins": 64,
+                  "engine_backend": "pallas", "routing_backend": "auto",
+                  "dtype": "float64"}}
+
+RUN = """
+import json, sys
+sys.path.insert(0, sys.argv[1])
+import jax
+from bench import harness
+harness.configure(harness.ROOT / ".bench_cache" / "jax")
+res = harness.run("toy.oos-predict", 2 ** 31 + 11, 0.3, False, 0.0,
+                  devices=lambda c: jax.devices()[:c],
+                  sizes={"traffic": {"batch_rows": 32, "pool_batches": 4,
+                                     "check_rows": 96}})
+print(json.dumps(dict(res, harness=harness.__file__)))
+"""
+
+
+def test_deployment_added_as_files_alone(tmp_path):
+    """A deployment of another kind (its own generator and labels, a
+    gradient-boosted forest, boosted proximities) is new files and entries
+    of BENCHMARK.json alone: its cell runs correct through the copy's own
+    harness, in a process of its own, with no copied file edited."""
+    root = tmp_path / "checkout"
+    shutil.copytree(harness.BENCH, root / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    copied = [p.relative_to(root) for p in (root / "bench").rglob("*")
+              if p.is_file()]
+    (root / "bench/generators/toy.py").write_text(GENERATOR)
+    (root / "bench/weights/boosted.py").write_text(BOOSTED)
+    (root / "bench/configs/toy-gbt.json").write_text(json.dumps(GBT))
+    spec = harness.load_spec()
+    spec["configs"].append({"name": "toy-gbt", "source": "test",
+                            "file": "bench/configs/toy-gbt.json",
+                            "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": "toy.oos-predict", "config": "toy-gbt",
+                              "traffic": "oos-predict", "chips": 1,
+                              "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "-c", RUN, str(harness.ROOT / "src")], cwd=root,
+        env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    assert res["harness"] == str(root / "bench" / "harness.py")
+    assert res["correct"] and res["failed"] == 0 and res["attempted"] > 0
+    assert res["checks"]["predict_gap"]["value"] <= 1e-5
+    for rel in copied:
+        assert filecmp.cmp(harness.BENCH.parent / rel, root / rel,
+                           shallow=False), rel
+
+
+@pytest.mark.parametrize("piece", ["generator", "weights", "forest key"])
+def test_missing_piece_is_named(jax_cpu, piece):
+    """A configuration that names a piece the benchmark does not have fails
+    before any work, naming the file looked for or the key."""
+    cfg = json.loads((harness.BENCH / "configs/higgs-rf100.json").read_text())
+    if piece == "generator":
+        cfg["generator"] = "no-such-data"
+        with pytest.raises(FileNotFoundError,
+                           match=r"generators/no-such-data\.py"):
+            data.training_rows(cfg)
+    elif piece == "weights":
+        with pytest.raises(FileNotFoundError,
+                           match=r"weights/no-such-method\.py"):
+            ReferenceForest([], np.zeros((0, 4)), np.zeros((4, 2)),
+                            np.zeros(4), "no-such-method")
+    else:
+        cfg["forest"]["learning_rate"] = 0.1
+        with pytest.raises(ValueError, match="learning_rate"):
+            harness.fit(cfg)

@@ -1,5 +1,6 @@
 """The benchmark's own arithmetic: trace reduction, work counts, peaks, the
 plain reference and the data generators."""
+import hashlib
 import json
 from pathlib import Path
 from types import SimpleNamespace
@@ -198,3 +199,65 @@ def test_higgs_layout():
     assert set(np.unique(X[:, 8::4][:, :4])) <= {0.0, 1.0865, 2.173}
     assert (X[:, 21:] > 0).all() and (X[:, [0, 5, 9, 13, 17]] > 0).all()
     assert abs(y.mean() - 0.53) < 0.02
+
+
+# ------------------------------------------------------- bit identity --
+# sha1 of the rows, the forest's leaf count and the reference's answers of
+# both configurations, as the harness first computed them with its
+# generators and weight assignments in data.py and reference.py: moving
+# them into files found by name moves no reading of the benchmark.
+SUMS = {
+    "covertype-rf100": {
+        "train": "46c74cf42d983b7849c33c981e19624b72b7dc08",
+        "query": "3022cefe91d8636f58169c46cfaf65f3c8f6b1b6",
+        "leaves": 2587,
+        "original.float64": "6de3121c071d90f3a65248286037128f8b6b93e2",
+        "original.bfloat16": "da1a54b116989f2b87058b4560c17626f8cc92fb",
+        "gap.float64": "b334e00d85623a25102c7f382a485595df9c56d7",
+        "gap.bfloat16": "45e2b80c7b006bd1056ea21d7c3931c8d429280c"},
+    "higgs-rf100": {
+        "train": "0a4d2d2e7430e973a1e7b329884b97ae18c33649",
+        "query": "7914b18cf5b0e124f58c029b840a7dc9121f773d",
+        "leaves": 3114,
+        "original.float64": "a6418ba5c366303b6d4a526198dc2e210d2f3421",
+        "original.bfloat16": "bd9647681f2846b6413a89ac773abc79940c5a39",
+        "gap.float64": "7999bd44ad428c48742866c80d75e783a8e1f012",
+        "gap.bfloat16": "97eaf4cd614fd973de416675a544373314c618b9"},
+}
+QUERY_SEED = 2 ** 31 + 7
+METHODS = ["original", "gap"]
+
+
+def _sha1(*arrays) -> str:
+    h = hashlib.sha1()
+    for a in arrays:
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
+def _small(name):
+    return dict(_cfg(name), n_train=2000)
+
+
+@pytest.mark.parametrize("name", sorted(SUMS))
+def test_rows_bit_identical(name):
+    cfg = _small(name)
+    assert _sha1(*data.training_rows(cfg)) == SUMS[name]["train"]
+    assert _sha1(data.query_rows(cfg, QUERY_SEED, 256)) == SUMS[name]["query"]
+
+
+@pytest.mark.parametrize("name", sorted(SUMS))
+def test_forest_and_reference_bit_identical(jax_cpu, name):
+    from bench import harness
+    cfg = _small(name)
+    cfg["forest"]["n_trees"] = 10
+    s = harness.fit(cfg)
+    assert s.kernel.ctx.total_leaves == SUMS[name]["leaves"]
+    f = s.kernel.forest
+    Xq = data.query_rows(cfg, QUERY_SEED, 256)
+    for method in METHODS:
+        ref = ReferenceForest(f.trees_, f.inbag_, s.X_train, s.y_train,
+                              method, tree_weights=f.tree_weights_)
+        for p in ("float64", "bfloat16"):
+            got = ref.predict(Xq, cfg["n_classes"], p)
+            assert _sha1(got) == SUMS[name][f"{method}.{p}"], (method, p)

@@ -56,6 +56,8 @@ class ForestKernel:
     #                                          binned codes / factor spill
     memory_budget_bytes: Optional[int] = None  # out-of-core: bound transient
     #                                            build + op intermediates
+    learning_rate: Optional[float] = None   # 'gbt' shrinkage (None: the
+    #                                         trainer's 0.1)
 
     forest: Optional[BaseForest] = None
     ctx: Optional[EnsembleContext] = None
@@ -67,6 +69,12 @@ class ForestKernel:
     # ---------------- fitting ----------------
     def fit_forest(self, X: np.ndarray, y: np.ndarray) -> "ForestKernel":
         cls = _MODEL_TYPES[self.model_type]
+        extra = {}
+        if self.learning_rate is not None:
+            if self.model_type != "gbt":
+                raise ValueError(f"learning_rate applies to model_type "
+                                 f"'gbt' only, not {self.model_type!r}")
+            extra["learning_rate"] = float(self.learning_rate)
         self.forest = cls(
             n_trees=self.n_trees, max_depth=self.max_depth,
             min_samples_leaf=self.min_samples_leaf,
@@ -74,7 +82,7 @@ class ForestKernel:
             task=self.task, seed=self.seed, n_jobs=self.n_jobs,
             routing_backend=self.routing_backend,
             tree_backend=self.tree_backend,
-            xb_scratch=self.scratch_dir)
+            xb_scratch=self.scratch_dir, **extra)
         self.forest.fit(X, y)
         return self
 

@@ -245,6 +245,9 @@ class ProximityEngine:
         # LRU; cached arrays are treated as immutable)
         self._label_cache: "OrderedDict[object, tuple]" = OrderedDict()
         self._app_cache: dict = {}    # application-level per-engine caches
+        # reference side of the pallas block kernels, staged once
+        # (``_block_factors``): a device (gl, w) pair in the kernel's layout
+        self._block_ref: Optional[tuple] = None
 
     # ---------------- query-state management ----------------
     @staticmethod
@@ -689,7 +692,12 @@ class ProximityEngine:
 
     def topk(self, k: int = 10, X: Optional[np.ndarray] = None,
              block: int = 4096) -> Tuple[np.ndarray, np.ndarray]:
-        """Per-query top-k proximities (values descending)."""
+        """Per-query top-k proximities (values descending).
+
+        On pallas the block kernel scores each row block against the
+        reference factors staged once (``_block_factors``) and the top k
+        are chosen on the device: a call uploads only its rows' (gl, q)
+        and fetches (rows, k) values and indices."""
         qs = self.query_state(X)
         cutover = X is None and self.W.shape[0] > self._SPARSE_TRAIN_CUTOVER
         _count_path("topk", self.backend, cutover)
@@ -700,11 +708,16 @@ class ProximityEngine:
         kk = min(k, self.W.shape[0])
         idx = np.zeros((n, k), dtype=np.int64)
         val = np.zeros((n, k), dtype=self.dtype)
-        gl_w_d = w_d = None
         if self.backend == "jax":
             block = min(block, self._op_row_chunk(self.W.shape[0]))
             with _x64_scope(self._use_x64):
                 gl_w_d, w_d = _stage(self.gl, self.w)
+        elif self.backend == "pallas":
+            import jax
+
+            from ..kernels import interpret_mode
+            ref = self._block_factors()
+            bd = self.block_dtype
         for i0 in range(0, n, block):
             i1 = min(i0 + block, n)
             if self.backend == "jax":
@@ -714,6 +727,18 @@ class ProximityEngine:
                     with span("engine.dispatch"):
                         v, ix = swlc_topk(gl_q_d, q_d, gl_w_d, w_d, kk)
                     del gl_q_d, q_d
+                    v, ix = _fetch(v, ix)
+            elif self.backend == "pallas":
+                from .jax_ops import swlc_block_topk
+                gl_q = qs.gl[i0:i1].astype(np.int32)
+                q = qs.q[i0:i1].astype(bd)
+                with jax.enable_x64(bd == np.float64):
+                    staged = _stage(gl_q, q)
+                    with span("engine.dispatch"):
+                        v, ix = swlc_block_topk(
+                            *staged, *ref, k=kk, n_ref=self.W.shape[0],
+                            interpret=interpret_mode())
+                    del staged
                     v, ix = _fetch(v, ix)
             else:
                 B = self.kernel_block(np.arange(i0, i1), X_rows=X)
@@ -725,6 +750,42 @@ class ProximityEngine:
             idx[i0:i1, :kk] = ix
             val[i0:i1, :kk] = v
         return idx, val
+
+    def _block_factors(self) -> tuple:
+        """Reference side of the pallas block kernels: gl as int32 and w in
+        ``block_dtype``, padded and transposed as
+        ``block_prox.pad_reference`` lays them out, staged once per engine
+        and kept on the device, as ``_ref_table`` keeps S: a warmed block
+        op uploads only its query side.  The staging is the
+        ``engine.ref_factors`` span (``bytes``: what is uploaded; no inner
+        ``engine.upload``, so a reader summing ``bytes`` counts it once).
+        Each lookup counts as ``hit`` or ``miss`` in
+        ``engine_ref_factors_total{backend,result}``."""
+        from ..obs.metrics import global_registry
+        hit = self._block_ref is not None
+        global_registry().counter(
+            "engine_ref_factors_total",
+            "reference factor lookups of the block kernels by result",
+            labels=("backend", "result")).labels(
+            backend=self.backend, result="hit" if hit else "miss").inc()
+        if hit:
+            return self._block_ref
+        import jax
+        import jax.numpy as jnp
+
+        from ..kernels.block_prox.block_prox import (BLOCK_W, T_CHUNK,
+                                                     pad_reference)
+        gl = self.gl.astype(np.int32)
+        w = self.w.astype(self.block_dtype)
+        with span("engine.ref_factors", bytes=int(gl.nbytes + w.nbytes)), \
+                jax.enable_x64(self.block_dtype == np.float64):
+            staged = jnp.asarray(gl), jnp.asarray(w)
+            with span("engine.dispatch"):
+                self._block_ref = pad_reference(
+                    *staged, block_w=BLOCK_W, t_chunk=T_CHUNK,
+                    dtype=self.block_dtype)
+            del staged
+        return self._block_ref
 
     # ---------------- accounting ----------------
     def memory_bytes(self) -> dict:

@@ -29,8 +29,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["swlc_matvec", "swlc_matmat", "swlc_bucket", "swlc_gather",
-           "swlc_block", "swlc_predict", "swlc_topk", "sharded_swlc_matmat",
-           "default_mesh"]
+           "swlc_block", "swlc_predict", "swlc_topk", "swlc_block_topk",
+           "sharded_swlc_matmat", "default_mesh"]
 
 
 def default_mesh(data_axis: str = "data",
@@ -206,6 +206,30 @@ def swlc_topk(gl_q: jax.Array, q: jax.Array, gl_w: jax.Array, w: jax.Array,
     """
     B = swlc_block(gl_q, q, gl_w, w)
     return jax.lax.top_k(B, k)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "n_ref", "interpret"))
+def swlc_block_topk(gl_q: jax.Array, q: jax.Array, glwt: jax.Array,
+                    wt: jax.Array, k: int, n_ref: int,
+                    interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
+    """Top-k proximities of query rows (gl_q, q), each (N_q, T), against
+    reference factors staged once in the block kernel's layout
+    (``block_prox.pad_reference``: (T_pad, N_w_pad)), of which the first
+    ``n_ref`` columns are real.  Returns (values, int32 indices), (N_q, k),
+    values descending.
+
+    Two stages, each under a ``jax.named_scope`` a device trace can read:
+    ``block``, the tree-tiled Pallas kernel's dense (N_q, N_w) block, and
+    ``select``, ``lax.top_k`` over its real columns.  Only the (N_q, k)
+    answer leaves the device.
+    """
+    from ..kernels.block_prox.block_prox import (BLOCK_Q, T_CHUNK,
+                                                 block_prox_padded, pad_query)
+    with jax.named_scope("block"):
+        glq, qq = pad_query(gl_q, q, BLOCK_Q, T_CHUNK, wt.dtype)
+        B = block_prox_padded(glq, qq, glwt, wt, interpret=interpret)
+    with jax.named_scope("select"):
+        return jax.lax.top_k(B[:gl_q.shape[0], :n_ref], k)
 
 
 def swlc_predict(gl_q, q, gl_w, w, Y, total_leaves: int,

@@ -195,12 +195,14 @@ def load_kernel(path, engine_backend: Optional[str] = None):
     fk = ForestKernel(**config)
 
     cls = _MODEL_TYPES[fk.model_type]
+    rate = {} if fk.learning_rate is None else \
+        {"learning_rate": float(fk.learning_rate)}
     forest = cls(n_trees=fk.n_trees, max_depth=fk.max_depth,
                  min_samples_leaf=fk.min_samples_leaf,
                  max_features=fk.max_features, n_bins=fk.n_bins,
                  task=fk.task, seed=fk.seed, n_jobs=fk.n_jobs,
                  routing_backend=fk.routing_backend,
-                 tree_backend=fk.tree_backend)
+                 tree_backend=fk.tree_backend, **rate)
     forest.trees_ = unpack_trees({k: arrays[f"tree_{k}"]
                                   for k in _TREE_KEYS})
     forest.inbag_ = np.ascontiguousarray(arrays["inbag"], dtype=np.int32)

@@ -7,13 +7,14 @@ import jax
 import jax.numpy as jnp
 
 from .. import interpret_mode
-from .block_prox import block_prox_pallas
+from .block_prox import BLOCK_Q, BLOCK_W, block_prox_pallas
 from .ref import block_prox_ref
 
 __all__ = ["block_prox"]
 
 
-def block_prox(gl_q, q, gl_w, w, block_q: int = 256, block_w: int = 256,
+def block_prox(gl_q, q, gl_w, w, block_q: int = BLOCK_Q,
+               block_w: int = BLOCK_W,
                use_pallas: bool = True, dtype=jnp.float32) -> jax.Array:
     """``dtype`` selects the accumulator/output precision.
 

@@ -59,6 +59,33 @@ def test_topk_identical_across_backends(rf_kernel_cache):
             np.take_along_axis(P, idx, axis=1), val, atol=1e-8)
 
 
+@pytest.mark.parametrize("model_type,method", [("rf", "original"),
+                                               ("gbt", "boosted")])
+def test_pallas_oos_topk_matches_scipy(model_type, method):
+    """Pallas top-k of held-out rows (the tiled kernel and the selection on
+    the device, interpret mode, float32 as on the chip) against scipy's:
+    the same values to 1e-6 relative, and every returned index realizes
+    its value (ties at rank k may pick other rows)."""
+    from repro.core.api import ForestKernel
+    from repro.data.synthetic import gaussian_classes
+    X, y = gaussian_classes(700, d=6, n_classes=2, sep=1.0, seed=21)
+    fk = ForestKernel(model_type=model_type, kernel_method=method,
+                      n_trees=13, max_depth=5, seed=0,
+                      max_features="sqrt" if model_type == "rf" else None,
+                      dtype=np.float32, engine_backend="scipy").fit(X, y)
+    pallas = ProximityEngine(fk.ctx, fk.assignment, forest=fk.forest,
+                             backend="pallas", dtype=np.float32)
+    Xq = X[:37] + 0.05
+    idx_ref, val_ref = fk.engine.topk(k=7, X=Xq)
+    idx, val = pallas.topk(k=7, X=Xq)
+    assert idx.shape == val.shape == (37, 7) and val.dtype == np.float32
+    np.testing.assert_allclose(val, val_ref, rtol=1e-6)
+    P = np.asarray(fk.engine.kernel_block(X_rows=Xq))
+    np.testing.assert_allclose(np.take_along_axis(P, idx, axis=1), val,
+                               rtol=1e-6)
+    assert all(len(set(r)) == 7 for r in idx)
+
+
 def test_kernel_block_identical_across_backends(rf_kernel_cache):
     fk, engines = _engines(rf_kernel_cache, "gap")
     rows, cols = np.arange(40), np.arange(10, 90)

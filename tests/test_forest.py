@@ -91,3 +91,51 @@ def test_min_samples_leaf(small_cls_data):
     rf = RandomForest(n_trees=4, min_samples_leaf=20, seed=0).fit(Xtr, ytr)
     for t in rf.trees_:
         assert t.leaf_counts().min() >= 20
+
+
+# ------------------------------------------- ForestKernel's learning_rate
+def _gbt_kernel(X, y, **kw):
+    from repro.core.api import ForestKernel
+    return ForestKernel(model_type="gbt", kernel_method="boosted", n_trees=6,
+                        max_features=None, seed=0, **kw).fit_forest(X, y)
+
+
+def test_forest_kernel_learning_rate_shapes_the_boosted_forest():
+    """``learning_rate`` reaches the boosted trainer: max_depth 7 (the root
+    counts as depth 1) grows XGBoost's depth-6 trees of at most 64 leaves,
+    and the rate moves every stage's fit and weight."""
+    rng = np.random.default_rng(13)
+    X = rng.normal(size=(3000, 8))      # noisy labels: trees fill depth
+    y = (rng.random(3000) < 1 / (1 + np.exp(-X[:, 0]))).astype(np.int64)
+    fast = _gbt_kernel(X, y, learning_rate=0.3, max_depth=7)
+    assert fast.forest.learning_rate == 0.3
+    n_leaves = lambda fk: [int((t.feature < 0).sum())
+                           for t in fk.forest.trees_]
+    assert 32 < max(n_leaves(fast)) <= 64, n_leaves(fast)
+    shallow = _gbt_kernel(X, y, learning_rate=0.3, max_depth=6)
+    assert 16 < max(n_leaves(shallow)) <= 32, n_leaves(shallow)
+    slow = _gbt_kernel(X, y, max_depth=7)
+    assert slow.forest.learning_rate == 0.1
+    assert not np.allclose(fast.forest.tree_weights_,
+                           slow.forest.tree_weights_)
+
+
+@pytest.mark.parametrize("model_type", ["rf", "gbt"])
+def test_forest_kernel_without_learning_rate_is_unchanged(model_type):
+    """Without the key every fit is bit-identical to the trainer's own
+    defaults; on a forest that has no rate the key is refused."""
+    from repro.core.api import ForestKernel
+    cls = {"rf": RandomForest, "gbt": GradientBoostedTrees}[model_type]
+    X, y = gaussian_classes(600, d=6, n_classes=2, seed=2)
+    fk = ForestKernel(model_type=model_type, n_trees=4, max_depth=6,
+                      seed=0).fit_forest(X, y)
+    own = cls(n_trees=4, max_depth=6, max_features="sqrt", seed=0).fit(X, y)
+    for a, b in zip(fk.forest.trees_, own.trees_):
+        for f in ("feature", "threshold", "left", "right", "value"):
+            assert np.array_equal(getattr(a, f), getattr(b, f)), f
+    if model_type == "gbt":
+        assert np.array_equal(fk.forest.tree_weights_, own.tree_weights_)
+    else:
+        with pytest.raises(ValueError, match="learning_rate"):
+            ForestKernel(model_type="rf", learning_rate=0.3,
+                         n_trees=2).fit_forest(X, y)

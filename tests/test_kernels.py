@@ -76,6 +76,55 @@ def test_block_prox_padding_no_phantom_collisions():
     np.testing.assert_allclose(got, want, atol=1e-6)
 
 
+@pytest.mark.parametrize("T", [1, 37, 100])
+def test_block_prox_tiles_trees(T):
+    """The kernel walks the trees in chunks of T_CHUNK, padding T with
+    sentinel trees: any T, a multiple of the chunk or not, agrees with the
+    oracle (float32, interpret mode)."""
+    from repro.kernels.block_prox.block_prox import T_CHUNK
+    assert T % T_CHUNK or T == 1
+    rng = np.random.default_rng(T)
+    gl_q = _rand_leafset(rng, 70, T, 4)
+    gl_w = _rand_leafset(rng, 300, T, 4)
+    q = rng.random((70, T)).astype(np.float32)
+    w = rng.random((300, T)).astype(np.float32)
+    got = np.asarray(block_prox(gl_q, q, gl_w, w, dtype=jnp.float32))
+    want = np.asarray(block_prox_ref(jnp.asarray(gl_q), jnp.asarray(q),
+                                     jnp.asarray(gl_w), jnp.asarray(w)))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("k", [1, 5, 40])
+def test_block_topk_against_dense_rows(k):
+    """The device top-k against reference factors staged in the kernel's
+    padded layout: each row's k largest proximities, at indices that
+    realize them.  Rows with fewer than k collisions pad with zeros at
+    real columns, never at the layout's padding (≥ n_ref)."""
+    from repro.core.jax_ops import swlc_block_topk
+    from repro.kernels.block_prox import block_prox as bp
+    rng = np.random.default_rng(k)
+    nq, nw, T = 20, 600, 11
+    gl_q, gl_w = _rand_leafset(rng, nq, T, 3), _rand_leafset(rng, nw, T, 3)
+    gl_q[:4] += 10 ** 6                     # four rows collide with nothing
+    q = rng.random((nq, T)).astype(np.float32)
+    w = rng.random((nw, T)).astype(np.float32)
+    P = np.asarray(block_prox_ref(jnp.asarray(gl_q), jnp.asarray(q),
+                                  jnp.asarray(gl_w), jnp.asarray(w)))
+    ref = bp.pad_reference(jnp.asarray(gl_w), jnp.asarray(w),
+                           block_w=bp.BLOCK_W, t_chunk=bp.T_CHUNK,
+                           dtype=np.dtype(np.float32))
+    assert ref[0].shape == (16, 1024)
+    v, ix = (np.asarray(a) for a in swlc_block_topk(
+        jnp.asarray(gl_q), jnp.asarray(q), *ref, k=k, n_ref=nw,
+        interpret=True))
+    assert v.shape == ix.shape == (nq, k) and ix.dtype == np.int32
+    np.testing.assert_allclose(v, -np.sort(-P, axis=1)[:, :k], rtol=1e-6)
+    assert ((ix >= 0) & (ix < nw)).all()
+    np.testing.assert_allclose(np.take_along_axis(P, ix, axis=1), v,
+                               rtol=1e-6)
+    assert (v[:4] == 0).all()
+
+
 def test_compiled_block_prox_refuses_float64(monkeypatch):
     """Off the CPU the kernel runs compiled, in float32 only: a float64
     request raises instead of silently computing in float32."""

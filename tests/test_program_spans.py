@@ -145,6 +145,54 @@ def test_block_op_upload_bytes(fitted, tmp_path, backend, op, monkeypatch):
         assert dispatch == [{}] * 3 and fetches == 3
 
 
+def test_pallas_topk_stages_the_reference_once(fitted, tmp_path):
+    """The pallas top-k stages its reference factors once per engine, in
+    an ``engine.ref_factors`` span whose bytes are those uploaded (int32
+    ids and block-dtype weights): the first call counts a miss in
+    ``engine_ref_factors_total``, every later call a hit, and a warmed
+    call uploads only the query side, n_q·T·(4 + itemsize) bytes."""
+    from repro.obs.metrics import global_registry
+    eng, (_, _, batches) = _kernel(fitted, "pallas").engine, fitted
+    n, T = eng.gl.shape
+    size = eng.block_dtype.itemsize
+    fam = global_registry().counter("engine_ref_factors_total",
+                                    labels=("backend", "result"))
+    count = lambda r: fam.labels(backend="pallas", result=r).value
+    before = count("miss"), count("hit")
+    first = _program_events(tmp_path / "first",
+                            lambda: eng.topk(k=5, X=batches[0]))
+    (_, _, _, stats), = [e for e in first if e[0] == "engine.ref_factors"]
+    assert stats == {"bytes": n * T * (4 + size)}
+    assert (count("miss"), count("hit")) == (before[0] + 1, before[1])
+    ev = _program_events(tmp_path, lambda: [eng.topk(k=5, X=b)
+                                            for b in batches[1:3]])
+    assert [e[0] for e in ev] == (ROUTE + DEVICE) * 2
+    for e in ev:
+        if e[0] == "engine.upload":
+            assert e[3]["bytes"] == len(batches[1]) * T * (4 + size)
+    assert (count("miss"), count("hit")) == (before[0] + 1, before[1] + 2)
+
+
+def test_topk_hlo_carries_block_and_select_scopes():
+    """The pallas top-k program names its two stages in the ``op_name``
+    metadata a device trace reports: the kernel under ``block``, the
+    selection under ``select``."""
+    from repro.kernels.block_prox import block_prox as bp
+    rng = np.random.default_rng(2)
+    nq, nw, T = 9, 300, 11
+    gl_w = jnp.asarray(rng.integers(0, 4, (nw, T)), jnp.int32)
+    w = jnp.asarray(rng.random((nw, T)), jnp.float32)
+    ref = bp.pad_reference(gl_w, w, block_w=bp.BLOCK_W, t_chunk=bp.T_CHUNK,
+                           dtype=np.dtype(np.float32))
+    text = jax_ops.swlc_block_topk.lower(
+        jnp.asarray(rng.integers(0, 4, (nq, T)), jnp.int32),
+        jnp.asarray(rng.random((nq, T)), jnp.float32), *ref, k=3, n_ref=nw,
+        interpret=True).compile().as_text()
+    paths = [p.split("/") for p in _op_names(text)]
+    assert any("block" in p for p in paths)
+    assert any("select" in p for p in paths)
+
+
 def test_span_without_jax(fitted, monkeypatch):
     """Where JAX is not installed a span is a no-op, so a host engine's
     out-of-sample queries run as before."""

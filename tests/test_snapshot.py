@@ -122,6 +122,19 @@ def test_warm_start_skips_weight_recompute(tmp_path, monkeypatch):
                                np.asarray(fk.kernel().todense()), atol=1e-8)
 
 
+def test_gbt_snapshot_restores_learning_rate(tmp_path):
+    X, y = gaussian_classes(300, d=6, n_classes=2, sep=3.0, seed=9)
+    fk = ForestKernel(model_type="gbt", kernel_method="boosted", n_trees=8,
+                      learning_rate=0.3, seed=0).fit(X, y)
+    p = tmp_path / "gbt.npz"
+    fk.save(p)
+    fk2 = ForestKernel.load(p)
+    assert fk2.learning_rate == fk2.forest.learning_rate == 0.3
+    Xq = np.ascontiguousarray(X[:20] + 1e-3)
+    np.testing.assert_allclose(fk2.forest.decision_function(Xq),
+                               fk.forest.decision_function(Xq), atol=1e-12)
+
+
 def test_gbt_snapshot_restores_base_score(tmp_path):
     X, y = gaussian_classes(300, d=6, n_classes=2, sep=3.0, seed=9)
     fk = ForestKernel(model_type="gbt", kernel_method="boosted",

@@ -12,7 +12,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.block_prox.block_prox import block_prox_pallas
+from repro.core.jax_ops import swlc_block_topk
+from repro.kernels.block_prox.block_prox import (BLOCK_Q, BLOCK_W, T_CHUNK,
+                                                 block_prox_pallas,
+                                                 block_vmem_bytes)
 from repro.kernels.histogram.histogram import (hist_vmem_bytes,
                                                histogram_pallas,
                                                moments_pallas)
@@ -54,6 +57,34 @@ def test_block_prox_compiles_for_v5e(one_chip):
         s((256, T), jnp.int32), s((256, T), jnp.float32),
         s((N, T), jnp.int32), s((N, T), jnp.float32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("trees", [100, 500])
+@pytest.mark.parametrize("op", ["block", "topk"])
+def test_block_kernels_compile_at_any_tree_count(one_chip, op, trees):
+    """A 1,024-row query batch against 100,000 reference rows at 100 and
+    500 trees (the top-k cells' shape): the kernel walks the trees in
+    chunks, so it compiles within ``block_vmem_bytes`` — the scoped-VMEM
+    limit it hands the compiler — whatever T.  Before the trees were
+    tiled, T = 100 asked for 18.21M against v5e's 16M."""
+    s = lambda shape, dt: _shape(one_chip, shape, dt)
+    nq, nw = 1024, 100_000
+    query = (s((nq, trees), jnp.int32), s((nq, trees), jnp.float32))
+    if op == "block":
+        fn = block_prox_pallas
+        args = query + (s((nw, trees), jnp.int32), s((nw, trees), jnp.float32))
+    else:
+        # the reference side as the engine stages it: transposed, padded
+        t_pad = -(-trees // T_CHUNK) * T_CHUNK
+        nw_pad = -(-nw // BLOCK_W) * BLOCK_W
+        fn = lambda a, b, c, d: swlc_block_topk(a, b, c, d, k=10, n_ref=nw)
+        args = query + (s((t_pad, nw_pad), jnp.int32),
+                        s((t_pad, nw_pad), jnp.float32))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert block_vmem_bytes(BLOCK_Q, BLOCK_W, T_CHUNK) <= 16 << 20
+    if op == "topk":
+        assert [o.shape for o in compiled.out_info] == [(nq, 10)] * 2
 
 
 @pytest.mark.parametrize("kind", ["histogram", "moments"])

@@ -25,6 +25,8 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from ..obs.metrics import global_registry
+
 __all__ = ["Tree", "TreeArrays", "route_tree", "route_forest_numpy",
            "route_forest_batched", "stack_leaf_values", "node_depths",
            "truncate_tree", "prefix_leaf_map", "pack_trees", "unpack_trees"]
@@ -180,6 +182,13 @@ def _route_batched_numpy(ta: "TreeArrays", X: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(out.reshape(T, n).T)
 
 
+def _count_route(path: str, threads: int) -> None:
+    global_registry().counter(
+        "forest_route_total",
+        "route_forest_batched calls by path and host routing threads",
+        labels=("path", "threads")).labels(path=path, threads=threads).inc()
+
+
 def route_forest_batched(ta: "TreeArrays", X: np.ndarray,
                          backend: str = "auto",
                          block_n: int = 1024) -> np.ndarray:
@@ -189,9 +198,16 @@ def route_forest_batched(ta: "TreeArrays", X: np.ndarray,
       "auto"    native C kernel when a host compiler is available, else the
                 numpy path (both bit-identical to the ``route_tree`` oracle)
       "native"  lazily-compiled C kernel (ctypes); error if no compiler
+                (threads and row blocks from the batch's shape,
+                ``_native.route_grain``)
       "numpy"   vectorized gather/compare/select with an active-lane set
       "jax"     jit'd vmap reference (float32 — TPU-native precision)
       "pallas"  TPU routing kernel; interpret mode off-TPU (float32)
+
+    Each call counts once in ``forest_route_total{path,threads}``: path
+    ``tiled`` (native, several threads), ``serial`` (native, one thread),
+    ``numpy``, ``jax`` or ``pallas``; threads the host threads that walked
+    the trees (0 on the device paths).
     """
     X = np.asarray(X, dtype=np.float64)
     if X.ndim != 2:
@@ -206,15 +222,19 @@ def route_forest_batched(ta: "TreeArrays", X: np.ndarray,
         from . import _native
         if _native.available():
             T, M = ta.feature.shape
-            return _native.route_native(*ta.flat(), T, M, X)
+            out, threads = _native.route_native(*ta.flat(), T, M, X)
+            _count_route("tiled" if threads > 1 else "serial", threads)
+            return out
         if backend == "native":
             raise RuntimeError("native routing backend unavailable "
                                "(no working C compiler)")
         backend = "numpy"
     if backend == "numpy":
+        _count_route("numpy", 1)
         return _route_batched_numpy(ta, X)
     if backend in ("jax", "pallas"):
         from ..kernels.leaf_route.ops import route
+        _count_route(backend, 0)
         return route(X, ta, block_n=block_n, use_pallas=(backend == "pallas"))
     raise ValueError(f"unknown routing backend {backend!r}; have "
                      "'auto' | 'native' | 'numpy' | 'jax' | 'pallas'")

@@ -141,6 +141,104 @@ def test_route_batched_exact_threshold_hits():
     _assert_backends_match([tr], X)
 
 
+def _chain_tree(rng: np.random.Generator, depth: int, d: int) -> Tree:
+    """A path of ``depth`` splits, each with one leaf child; the path goes
+    on to the side that about 90% of standard normal values take, so
+    walks run deep."""
+    n_nodes = 2 * depth + 1
+    feature = np.full(n_nodes, -1, np.int32)
+    threshold = np.zeros(n_nodes, np.float32)
+    left = np.zeros(n_nodes, np.int32)
+    right = np.zeros(n_nodes, np.int32)
+    node = 0
+    for k in range(depth):
+        on, off = 2 * k + 1, 2 * k + 2
+        feature[node] = rng.integers(0, d)
+        if rng.random() < 0.5:                  # the path goes left
+            threshold[node] = np.float32(1.3)
+            left[node], right[node] = on, off
+        else:
+            threshold[node] = np.float32(-1.3)
+            left[node], right[node] = off, on
+        node = on
+    leaves = feature == -1
+    leaf_id = np.full(n_nodes, -1, np.int32)
+    leaf_id[leaves] = np.arange(leaves.sum(), dtype=np.int32)
+    return Tree(feature=feature, threshold=threshold, left=left, right=right,
+                leaf_id=leaf_id, value=np.ones((n_nodes, 2), np.float32),
+                n_node_samples=np.ones(n_nodes, np.int32), depth=depth)
+
+
+def _mixed_forest(n_trees: int, d: int, seed: int) -> list:
+    """Stumps, bushy trees and deep chains in one padded ensemble."""
+    rng = np.random.default_rng(seed)
+    kinds = [lambda: _single_node_tree(),
+             lambda: _random_tree(rng, 255, d),
+             lambda: _chain_tree(rng, 40, d),
+             lambda: _random_tree(rng, 15, d)]
+    return [kinds[t % len(kinds)]() for t in range(n_trees)]
+
+
+# 80 features put 2,049 and 4,097 rows over route_forest's 1 MiB row block
+# (2 and 3 blocks), so tasks cross row blocks as well as trees.
+@pytest.mark.parametrize("threads", [1, 2, 3])
+@pytest.mark.parametrize("n_trees", [1, 3, 37])
+@pytest.mark.parametrize("n", [1, 7, 2049, 4097])
+def test_native_route_tiles_match_oracle(n, n_trees, threads):
+    from repro.forest import _native
+    if not _native.available():
+        pytest.skip("no host C compiler")
+    d = 80
+    trees = _mixed_forest(n_trees, d, seed=n_trees)
+    rng = np.random.default_rng(n)
+    X = rng.normal(size=(n, d))
+    X[rng.random(size=X.shape) < 0.05] = np.nan
+    ta = TreeArrays.from_trees(trees)
+    T, M = ta.feature.shape
+    got, used = _native.route_native(*ta.flat(), T, M, X, threads=threads)
+    np.testing.assert_array_equal(got, route_forest_numpy(trees, X))
+    row_block, _ = _native.route_grain(n, T, d, threads)
+    assert (row_block < n) == (n > 2000)
+    tasks = -(-n // row_block) * T
+    assert used == (1 if n * T < _native._SERIAL_WALKS
+                    else min(threads, tasks))
+
+
+def _route_counts():
+    from repro.obs.metrics import global_registry
+    fam = global_registry().counter("forest_route_total",
+                                    labels=("path", "threads"))
+    return {k: c.value for k, c in fam.items()}
+
+
+@pytest.mark.parametrize("n,n_trees,backend,path", [
+    (20, 3, "auto", "serial"),         # 60 walks: one thread
+    (512, 8, "auto", "tiled"),         # 4,096 walks on 8 tasks
+    (20, 3, "numpy", "numpy"),
+])
+def test_route_counter_follows_shape(n, n_trees, backend, path):
+    """``forest_route_total{path,threads}`` counts each call once, on the
+    path the shape chose, with the threads that walked the trees."""
+    from repro.forest import _native
+    if backend == "auto" and not _native.available():
+        pytest.skip("no host C compiler")
+    rng = np.random.default_rng(n)
+    trees = [_random_tree(rng, 31, d=4) for _ in range(n_trees)]
+    ta = TreeArrays.from_trees(trees)
+    X = rng.normal(size=(n, 4))
+    threads = 1
+    if path == "tiled":
+        threads = min(int(_native._lib.max_threads()), n_trees)
+        if threads == 1:
+            path = "serial"                # a one-thread host never tiles
+    before = _route_counts()
+    route_forest_batched(ta, X, backend=backend)
+    after = _route_counts()
+    moved = {k: v - before.get(k, 0.0) for k, v in after.items()
+             if v != before.get(k, 0.0)}
+    assert moved == {(path, str(threads)): 1.0}
+
+
 def test_forest_apply_uses_batched_path(small_cls_data):
     Xtr, ytr, Xte, _ = small_cls_data
     rf = RandomForest(n_trees=6, seed=1).fit(Xtr, ytr)

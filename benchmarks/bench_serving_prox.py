@@ -520,7 +520,7 @@ def main() -> None:
     ap.add_argument("--d", type=int, default=20)
     ap.add_argument("--trees", type=int, default=50)
     ap.add_argument("--backend", default="auto",
-                    choices=["auto", "scipy", "jax", "pallas", "native"])
+                    choices=["auto", "scipy", "pallas", "native"])
     ap.add_argument("--prototypes", type=int, default=20)
     ap.add_argument("--proto-k", type=int, default=100)
     ap.add_argument("--slots", type=int, default=64)

@@ -1,6 +1,6 @@
 """Benchmark orchestrator — one section per paper table/figure.
 
-  PYTHONPATH=src:. python -m benchmarks.run [--full] [--skip roofline]
+  PYTHONPATH=src:. python -m benchmarks.run [--full] [--skip SECTION ...]
 
 Prints CSV blocks per section (tee'd to bench_output.txt by the runner).
 """
@@ -20,7 +20,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="paper-scale sizes (slow; default is CI-scale)")
-    ap.add_argument("--skip", nargs="*", default=[])
+    ap.add_argument("--skip", nargs="*", default=[],
+                    help="sections to skip: oob scaling prediction leafpca "
+                         "kernels")
     args = ap.parse_args()
     configure_compile_cache()
     fast = not args.full
@@ -51,17 +53,6 @@ def main() -> None:
         _section("Pallas kernel micro-benchmarks (interpret-mode shapes)")
         from benchmarks.bench_kernels import run as run_kern
         run_kern(fast=fast)
-
-    if "roofline" not in args.skip:
-        _section("§Roofline — per (arch x shape) from dry-run records")
-        from benchmarks.roofline import report
-        try:
-            rows = report()
-            if not rows:
-                print("(no dry-run records found — run "
-                      "`python -m repro.launch.dryrun --all --both-meshes` first)")
-        except Exception as e:  # records may be in-flight
-            print(f"roofline report unavailable: {e}")
 
     print(f"\n[benchmarks] total {time.time()-t0:.1f}s", flush=True)
 

@@ -18,8 +18,8 @@ One-chip phases, each checked against a host reference:
   fit        ``tree_backend="jax"``: Pallas histogram kernels and split
              scoring in float64 (x64) on the device; the trees must equal
              ``tree_backend="native"``'s field for field.
-  factorize  engines on ``pallas`` and ``jax`` (device) and ``scipy``
-             (the float64 reference) over the same forest.
+  factorize  engines on ``pallas`` (device) and ``scipy`` (the float64
+             reference) over the same forest.
   engine     predict / row sums / kernel block / top-k / squared row sums,
              training-set and OOS, against the scipy engine.
   serve      ``ForestKernel.serve_tiered`` answers predict, topk, outlier,
@@ -286,9 +286,8 @@ def phase_factorize(fk: ForestKernel, rep: Report) -> dict:
     kernels = {}
     with rep.phase("factorize (pallas engine)"):
         kernels["pallas"] = fk.build_kernel_cache()
-    for backend in ("jax", "scipy"):
-        with rep.phase(f"factorize ({backend} engine)"):
-            kernels[backend] = kernel_on(fk, backend)
+    with rep.phase("factorize (scipy engine)"):
+        kernels["scipy"] = kernel_on(fk, "scipy")
     eng = kernels["pallas"].engine
     rep.log(f"   factors: {eng.gl.shape[0]} rows x {eng.gl.shape[1]} trees, "
             f"{eng.total_leaves} leaves")
@@ -300,30 +299,30 @@ def phase_engine(cfg: Config, kernels: dict, Xte, rep: Report) -> None:
     y = kernels["scipy"].ctx.y
     C = cfg.n_classes
     Xq = Xte[:cfg.rows]
-    for backend in ("pallas", "jax"):
-        eng = kernels[backend].engine
-        dt, bdt = eng.dtype.name, eng.block_dtype.name
-        with rep.phase(f"engine ops ({backend})"):
-            rep.check(f"{backend} predict train", eng.predict(y, C),
-                      ref.predict(y, C), dt)
-            rep.check(f"{backend} predict oos", eng.predict(y, C, X=Xte),
-                      ref.predict(y, C, X=Xte), dt)
-            rep.check(f"{backend} row_sums train", eng.row_sums(),
-                      ref.row_sums(), dt)
-            want_block = ref.kernel_block(X_rows=Xq)
-            rep.check(f"{backend} kernel_block oos",
-                      eng.kernel_block(X_rows=Xq), want_block, bdt)
-            idx, val = eng.topk(cfg.k, X=Xq)
-            want_idx, want_val = ref.topk(cfg.k, X=Xq)
-            rep.check(f"{backend} topk oos values", val, want_val, bdt)
-            rep.check(f"{backend} topk oos P[i, index]", val,
-                      np.take_along_axis(want_block, idx, axis=1), bdt)
-            rep.check(f"{backend} squared_row_sums oos",
-                      eng.squared_row_sums(class_ids=y, n_classes=C, X=Xq),
-                      ref.squared_row_sums(class_ids=y, n_classes=C, X=Xq),
-                      bdt)
-        rep.log(f"   {backend} dtypes: matmat/predict/row_sums {dt}; "
-                f"kernel_block/topk/squared_row_sums {bdt}")
+    backend = "pallas"
+    eng = kernels[backend].engine
+    dt, bdt = eng.dtype.name, eng.block_dtype.name
+    with rep.phase(f"engine ops ({backend})"):
+        rep.check(f"{backend} predict train", eng.predict(y, C),
+                  ref.predict(y, C), dt)
+        rep.check(f"{backend} predict oos", eng.predict(y, C, X=Xte),
+                  ref.predict(y, C, X=Xte), dt)
+        rep.check(f"{backend} row_sums train", eng.row_sums(),
+                  ref.row_sums(), dt)
+        want_block = ref.kernel_block(X_rows=Xq)
+        rep.check(f"{backend} kernel_block oos",
+                  eng.kernel_block(X_rows=Xq), want_block, bdt)
+        idx, val = eng.topk(cfg.k, X=Xq)
+        want_idx, want_val = ref.topk(cfg.k, X=Xq)
+        rep.check(f"{backend} topk oos values", val, want_val, bdt)
+        rep.check(f"{backend} topk oos P[i, index]", val,
+                  np.take_along_axis(want_block, idx, axis=1), bdt)
+        rep.check(f"{backend} squared_row_sums oos",
+                  eng.squared_row_sums(class_ids=y, n_classes=C, X=Xq),
+                  ref.squared_row_sums(class_ids=y, n_classes=C, X=Xq),
+                  bdt)
+    rep.log(f"   {backend} dtypes: matmat/predict/row_sums {dt}; "
+            f"kernel_block/topk/squared_row_sums {bdt}")
 
 
 def _tier_refs(srv, ref, cfg: Config) -> dict:
@@ -349,88 +348,80 @@ def phase_serve(cfg: Config, kernels: dict, ytr, Xte, rep: Report) -> None:
     from repro.applications.embed import ProximityEmbedding
     from repro.applications.outliers import (oos_outlier_scores,
                                              train_outlier_stats)
-    from repro.applications.prototypes import CompressedProximityEngine
     ref_kernel = kernels["scipy"]
     labeled = np.random.default_rng(cfg.seed).random(len(ytr)) < 0.1
     C = cfg.n_classes
-    ce = None
-    for backend in ("pallas", "jax"):
-        k = kernels[backend]
-        eng = k.engine
-        with rep.phase(f"serve setup ({backend}): propagation, embedding, "
-                       f"compression"):
-            prop = k.propagate_labels(labeled, online=True)
-            emb = ProximityEmbedding(n_components=2, seed=cfg.seed).fit(eng)
-            if ce is None:
-                ce = k.compress(n_prototypes=cfg.n_prototypes, k=cfg.proto_k)
-            else:
-                # prototype selection is a training-set top-k, which runs
-                # on the host cutover: select once, view it on this engine
-                ce = CompressedProximityEngine(
-                    eng, ce.prototype_indices_, labels=ce.prototype_labels_)
-            srv = k.serve_tiered(prefix_depth=cfg.prefix_depth,
-                                 compressed_engine=ce, n_slots=64,
-                                 escalate_margin=0.3, propagator=prop,
-                                 embedding=emb)
-        r = cfg.rows
-        reqs = [("predict", Xte[0:r]), ("predict", Xte[r:2 * r]),
-                ("topk", Xte[2 * r:3 * r], cfg.k), ("outlier", Xte[3 * r:4 * r]),
-                ("propagate", Xte[4 * r:5 * r]), ("embed", Xte[5 * r:6 * r])]
-        with rep.phase(f"serve ({backend}): {len(reqs)} requests"):
-            uids = [srv.submit(*q) for q in reqs]
-            srv.run_until_drained()
-        done = {t.uid: t for t in srv.finished}
-        refs = _tier_refs(srv, ref_kernel.engine, cfg)
-        for uid, q in zip(uids, reqs):
-            kind, Xr = q[0], q[1]
-            t = done.get(uid)
-            if t is None or t.result is None:
-                rep._fail(f"serve {backend} {kind}: no answer "
-                          f"(shed={getattr(t, 'shed', None)}, "
-                          f"failed={getattr(t, 'failed', None)})")
-                continue
-            tier = t.final_tier
-            reng, ty, tobj = refs[tier]
-            teng = tobj.engine
-            name = (f"{backend} serve {kind} via {'->'.join(t.tier_path)} "
-                    f"(answered by {tier})")
-            res = t.result
-            if kind == "predict":
-                rep.check(name, res["scores"],
-                          reng.predict(ty, n_classes=C, X=Xr),
-                          teng.dtype.name)
-            elif kind == "topk":
-                bdt = teng.block_dtype.name
-                full = ref_kernel.engine.kernel_block(X_rows=Xr)
-                idx, val = res["indices"], res["values"]
-                at = np.where(idx >= 0, np.take_along_axis(
-                    full, np.maximum(idx, 0), axis=1), 0.0)
-                rep.check(name + " P[i, index]", val, at, bdt)
-                want = reng.topk(k=q[2], X=Xr)[1]
-                rep.check(name + " values", val, want, bdt)
-            elif kind == "outlier":
-                stats = train_outlier_stats(reng, ty, n_classes=C)
-                want, cls = oos_outlier_scores(reng, ty, Xr, n_classes=C,
-                                               return_classes=True)
-                # compare raw n_c / Σ P² (what the device computed) — the
-                # median/MAD normalization would rescale the error
-                mad, med = stats["mad"][cls], stats["median"][cls]
-                rep.check(name + " raw", res["scores"] * mad + med,
-                          want * mad + med, teng.block_dtype.name)
-            elif kind == "propagate":
-                # the served field's OOS projection, rows scaled to sum 1
-                Fb = reng.matmat(prop.F, X=Xr, normalized=True)
-                rep.check(name, res["scores"],
-                          Fb / np.maximum(Fb.sum(axis=1, keepdims=True),
-                                          np.finfo(np.float64).tiny),
-                          teng.dtype.name)
-            else:
-                rep.check(name, res["embedding"],
-                          reng.matmat(emb._nystrom, X=Xr), teng.dtype.name)
-        st = srv.stats()
-        rep.log(f"   {backend} serving: {st['requests']} requests, "
-                f"escalations {st['escalations']}, shed {st['shed']}, "
-                f"timeouts {st['timeouts']}")
+    backend = "pallas"
+    k = kernels[backend]
+    eng = k.engine
+    with rep.phase(f"serve setup ({backend}): propagation, embedding, "
+                   f"compression"):
+        prop = k.propagate_labels(labeled, online=True)
+        emb = ProximityEmbedding(n_components=2, seed=cfg.seed).fit(eng)
+        ce = k.compress(n_prototypes=cfg.n_prototypes, k=cfg.proto_k)
+        srv = k.serve_tiered(prefix_depth=cfg.prefix_depth,
+                             compressed_engine=ce, n_slots=64,
+                             escalate_margin=0.3, propagator=prop,
+                             embedding=emb)
+    r = cfg.rows
+    reqs = [("predict", Xte[0:r]), ("predict", Xte[r:2 * r]),
+            ("topk", Xte[2 * r:3 * r], cfg.k), ("outlier", Xte[3 * r:4 * r]),
+            ("propagate", Xte[4 * r:5 * r]), ("embed", Xte[5 * r:6 * r])]
+    with rep.phase(f"serve ({backend}): {len(reqs)} requests"):
+        uids = [srv.submit(*q) for q in reqs]
+        srv.run_until_drained()
+    done = {t.uid: t for t in srv.finished}
+    refs = _tier_refs(srv, ref_kernel.engine, cfg)
+    for uid, q in zip(uids, reqs):
+        kind, Xr = q[0], q[1]
+        t = done.get(uid)
+        if t is None or t.result is None:
+            rep._fail(f"serve {backend} {kind}: no answer "
+                      f"(shed={getattr(t, 'shed', None)}, "
+                      f"failed={getattr(t, 'failed', None)})")
+            continue
+        tier = t.final_tier
+        reng, ty, tobj = refs[tier]
+        teng = tobj.engine
+        name = (f"{backend} serve {kind} via {'->'.join(t.tier_path)} "
+                f"(answered by {tier})")
+        res = t.result
+        if kind == "predict":
+            rep.check(name, res["scores"],
+                      reng.predict(ty, n_classes=C, X=Xr),
+                      teng.dtype.name)
+        elif kind == "topk":
+            bdt = teng.block_dtype.name
+            full = ref_kernel.engine.kernel_block(X_rows=Xr)
+            idx, val = res["indices"], res["values"]
+            at = np.where(idx >= 0, np.take_along_axis(
+                full, np.maximum(idx, 0), axis=1), 0.0)
+            rep.check(name + " P[i, index]", val, at, bdt)
+            want = reng.topk(k=q[2], X=Xr)[1]
+            rep.check(name + " values", val, want, bdt)
+        elif kind == "outlier":
+            stats = train_outlier_stats(reng, ty, n_classes=C)
+            want, cls = oos_outlier_scores(reng, ty, Xr, n_classes=C,
+                                           return_classes=True)
+            # compare raw n_c / Σ P² (what the device computed) — the
+            # median/MAD normalization would rescale the error
+            mad, med = stats["mad"][cls], stats["median"][cls]
+            rep.check(name + " raw", res["scores"] * mad + med,
+                      want * mad + med, teng.block_dtype.name)
+        elif kind == "propagate":
+            # the served field's OOS projection, rows scaled to sum 1
+            Fb = reng.matmat(prop.F, X=Xr, normalized=True)
+            rep.check(name, res["scores"],
+                      Fb / np.maximum(Fb.sum(axis=1, keepdims=True),
+                                      np.finfo(np.float64).tiny),
+                      teng.dtype.name)
+        else:
+            rep.check(name, res["embedding"],
+                      reng.matmat(emb._nystrom, X=Xr), teng.dtype.name)
+    st = srv.stats()
+    rep.log(f"   {backend} serving: {st['requests']} requests, "
+            f"escalations {st['escalations']}, shed {st['shed']}, "
+            f"timeouts {st['timeouts']}")
 
 
 def report_paths(rep: Report) -> None:
@@ -472,7 +463,7 @@ def run_sharded(cfg: Config, rep: Report) -> None:
     with rep.phase("fit (tree_backend=native, host)"):
         fk = ForestKernel(kernel_method="gap", n_trees=cfg.n_trees,
                           seed=cfg.seed, tree_backend="native",
-                          engine_backend="jax").fit(Xtr, ytr)
+                          engine_backend="pallas").fit(Xtr, ytr)
     ref = kernel_on(fk, "scipy").engine
     eng = fk.engine
     C = cfg.n_classes
@@ -485,9 +476,10 @@ def run_sharded(cfg: Config, rep: Report) -> None:
                   f"not 'sharded'")
     with rep.phase("one-device segment matmat"):
         with jax.enable_x64(True):
-            one = np.asarray(jax_ops.swlc_matmat(
-                jnp.asarray(eng.gl), jnp.asarray(eng.q), jnp.asarray(eng.w),
-                jnp.asarray(V), eng.total_leaves, t_chunk=1))
+            gl, w = jnp.asarray(eng.gl), jnp.asarray(eng.w)
+            one = np.asarray(jax_ops._swlc_product(
+                gl, jnp.asarray(eng.q), gl, w, jnp.asarray(V),
+                eng.total_leaves, t_chunk=1))
     dt = eng.dtype.name
     rep.check("sharded matmat vs one device", got, one, dt)
     rep.check("sharded matmat vs scipy", got, ref.matmat(V), dt)

@@ -20,7 +20,7 @@ def main() -> None:
     ap.add_argument("--d", type=int, default=12)
     ap.add_argument("--trees", type=int, default=30)
     ap.add_argument("--backend", default="scipy",
-                    choices=["scipy", "jax", "pallas"])
+                    choices=["scipy", "pallas"])
     args = ap.parse_args()
 
     X, y = gaussian_classes(args.n, d=args.d, n_classes=4, sep=3.0, seed=0)

@@ -28,7 +28,7 @@ def main() -> None:
     ap.add_argument("--d", type=int, default=12)
     ap.add_argument("--trees", type=int, default=30)
     ap.add_argument("--backend", default="auto",
-                    choices=["auto", "scipy", "jax", "pallas", "native"])
+                    choices=["auto", "scipy", "pallas", "native"])
     ap.add_argument("--slots", type=int, default=32)
     ap.add_argument("--trace-out", default="trace.json",
                     help="Chrome-trace JSON output path ('' to skip)")

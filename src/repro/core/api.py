@@ -48,7 +48,7 @@ class ForestKernel:
     n_bins: int = 64
     seed: int = 0
     dtype: type = np.float64
-    engine_backend: str = "scipy"    # 'scipy' | 'jax' | 'pallas' | 'native'
+    engine_backend: str = "scipy"    # 'scipy' | 'pallas' (device) | 'native'
     routing_backend: str = "auto"    # 'auto'|'native'|'numpy'|'jax'|'pallas'
     tree_backend: str = "auto"       # trainer: 'auto' | 'numpy' | 'native' | 'jax'
     n_jobs: int = 0                  # tree-fitting workers (0 = auto)

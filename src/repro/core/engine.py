@@ -12,13 +12,13 @@ the hot paths need:
 Backends
 --------
 ``scipy``   CSR sparse·sparseᵀ products (the paper's reference path).
-``jax``     segment-sum factorization (``core.jax_ops``) — O(N T) with
-            static shapes; runs under x64 when the engine dtype is float64
-            so results match scipy to ~1e-12.
-``pallas``  same segment-sum matvec/matmat, but dense block queries and
-            top-k go through the ``block_prox`` Pallas kernel (interpret
-            mode on the CPU; compiled, it computes in float32 —
-            ``block_dtype`` says which).
+``pallas``  the device backend: matvec / matmat / predict / row sums by
+            the segment-sum factorization (``core.jax_ops``), O(N T) with
+            static shapes, under x64 when the engine dtype is float64 so
+            results match scipy to ~1e-12; dense block queries and top-k
+            through the ``block_prox`` Pallas kernel (interpret mode on the
+            CPU; compiled, it computes in float32 — ``block_dtype`` says
+            which).
 ``native``  the lazily-compiled C kernels of ``forest._native`` (the same
             ``.so`` as the native router): bucket/gather matmat and dense
             collision blocks, accumulating in float64 like scipy.  Needs a
@@ -26,11 +26,11 @@ Backends
 
 Serving note: the bucket table S = Wᵀ V of every factored product depends
 only on the reference side, so for narrow V it is LRU-cached by V content
-on every backend: a host array on scipy and native, a device array on jax
-and pallas (built there by ``jax_ops.swlc_bucket`` and never copied back).
+on every backend: a host array on scipy and native, a device array on
+pallas (built there by ``jax_ops.swlc_bucket`` and never copied back).
 A serving loop calling ``predict(X=batch)`` every tick with the same
 labels pays the O(N T C) bucket once and only the O(n_batch T C)
-query-side gather per tick; on jax / pallas it uploads only the batch's
+query-side gather per tick; on pallas it uploads only the batch's
 (gl, q).
 
 No path in this module iterates over trees in Python.
@@ -56,7 +56,7 @@ from .leafmap import build_leaf_map
 __all__ = ["ProximityEngine", "PrefixProximityEngine", "QueryState",
            "ENGINE_BACKENDS", "prediction_margin"]
 
-ENGINE_BACKENDS = ("scipy", "jax", "pallas", "native")
+ENGINE_BACKENDS = ("scipy", "pallas", "native")
 
 
 @dataclasses.dataclass
@@ -98,17 +98,14 @@ def _fetch(*outs) -> tuple:
         return tuple(np.asarray(o) for o in outs)
 
 
-_DEVICE_BACKENDS = ("jax", "pallas")
-
-
 def _count_path(op: str, backend: str, cutover: bool = False) -> None:
     """Count where an engine op computed its proximities, in the global
-    ``engine_op_path_total{op,backend,path}`` family: ``device`` (jax /
-    pallas arrays), ``host`` (scipy / native) or ``host_cutover`` (a device
-    backend's training-set op above ``_SPARSE_TRAIN_CUTOVER``, run as
-    scipy CSR) — so a device run can tell its device work from host work."""
+    ``engine_op_path_total{op,backend,path}`` family: ``device`` (pallas
+    arrays), ``host`` (scipy / native) or ``host_cutover`` (a pallas
+    training-set op above ``_SPARSE_TRAIN_CUTOVER``, run as scipy CSR) —
+    so a device run can tell its device work from host work."""
     from ..obs.metrics import global_registry
-    on_device = backend in _DEVICE_BACKENDS
+    on_device = backend == "pallas"
     path = ("host_cutover" if on_device else "host") if cutover else \
         ("device" if on_device else "host")
     global_registry().counter(
@@ -229,7 +226,7 @@ class ProximityEngine:
         self._train_row_sums: Optional[np.ndarray] = None
         self.last_matmat_path: Optional[str] = None   # 'sharded' | 'segment'
         # reference bucket tables S = Wᵀ V (serving), LRU of key ->
-        # (keepalive V | None, S); S is a device array on jax / pallas.
+        # (keepalive V | None, S); S is a device array on pallas.
         # Sized above the number of distinct fixed tables a mixed serving
         # tick touches (labels, ones, propagation field, Nyström basis,
         # per-class masks) so rotating inserts from iterative solvers
@@ -344,7 +341,7 @@ class ProximityEngine:
                          ref_key=None) -> np.ndarray:
         """Backend dispatch for (P V) on an already-resolved query state."""
         _count_path("matmat", self.backend)
-        if self.backend in _DEVICE_BACKENDS:
+        if self.backend == "pallas":
             return self._segment_matmat(qs, V, ref_key)
         S = self._ref_table(V, key=ref_key)
         if self.backend == "scipy":
@@ -355,7 +352,7 @@ class ProximityEngine:
     def _ref_table(self, V: np.ndarray, key=None):
         """Reference bucket table S = Wᵀ V of the factored product
         P V = Q (Wᵀ V) — the half that does not depend on the query rows:
-        a host array on scipy / native; on jax / pallas a device array of
+        a host array on scipy / native; on pallas a device array of
         (total_leaves + 1) rows (``jax_ops.swlc_bucket``), which stays on
         the device, so a cached table costs a call no transfer at all.
 
@@ -562,20 +559,6 @@ class ProximityEngine:
         if self.backend == "native":
             out = _native.prox_block_native(gl_q, q, gl_w, w)
             return out.astype(self.dtype, copy=False)
-        if self.backend == "jax":
-            from .jax_ops import swlc_block
-            out = np.empty((len(rows), gl_w.shape[0]), dtype=self.dtype)
-            step = self._op_row_chunk(gl_w.shape[0])
-            with _x64_scope(self._use_x64):
-                gl_w_d, w_d = _stage(gl_w, w)
-                for i0 in range(0, len(rows), step):
-                    gl_q_d, q_d = _stage(gl_q[i0:i0 + step],
-                                         q[i0:i0 + step])
-                    with span("engine.dispatch"):
-                        blk = swlc_block(gl_q_d, q_d, gl_w_d, w_d)
-                    del gl_q_d, q_d
-                    out[i0:i0 + step] = _fetch(blk)[0]
-            return out
         from ..kernels.block_prox.ops import block_prox
         # the wrapper stages its own inputs and dispatches the kernel: the
         # span's bytes are those of the host arrays it is handed
@@ -593,8 +576,8 @@ class ProximityEngine:
 
         With ``class_ids`` (N_ref,) the sum is bucketed by reference class:
         out[i, c] = Σ_{j: class_ids[j]=c} P(i,j)², shape (Nq, n_classes).
-        Streamed in row blocks (sparse on scipy, dense device blocks on
-        jax/pallas) — never a full dense P.
+        Streamed in row blocks (sparse on scipy, dense blocks on native and
+        pallas) — never a full dense P.
         """
         qs = self.query_state(X)
         n = qs.Q.shape[0]
@@ -708,11 +691,7 @@ class ProximityEngine:
         kk = min(k, self.W.shape[0])
         idx = np.zeros((n, k), dtype=np.int64)
         val = np.zeros((n, k), dtype=self.dtype)
-        if self.backend == "jax":
-            block = min(block, self._op_row_chunk(self.W.shape[0]))
-            with _x64_scope(self._use_x64):
-                gl_w_d, w_d = _stage(self.gl, self.w)
-        elif self.backend == "pallas":
+        if self.backend == "pallas":
             import jax
 
             from ..kernels import interpret_mode
@@ -720,15 +699,7 @@ class ProximityEngine:
             bd = self.block_dtype
         for i0 in range(0, n, block):
             i1 = min(i0 + block, n)
-            if self.backend == "jax":
-                from .jax_ops import swlc_topk
-                with _x64_scope(self._use_x64):
-                    gl_q_d, q_d = _stage(qs.gl[i0:i1], qs.q[i0:i1])
-                    with span("engine.dispatch"):
-                        v, ix = swlc_topk(gl_q_d, q_d, gl_w_d, w_d, kk)
-                    del gl_q_d, q_d
-                    v, ix = _fetch(v, ix)
-            elif self.backend == "pallas":
+            if self.backend == "pallas":
                 from .jax_ops import swlc_block_topk
                 gl_q = qs.gl[i0:i1].astype(np.int32)
                 q = qs.q[i0:i1].astype(bd)

@@ -26,10 +26,9 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
-__all__ = ["swlc_matvec", "swlc_matmat", "swlc_bucket", "swlc_gather",
-           "swlc_block", "swlc_predict", "swlc_topk", "swlc_block_topk",
+__all__ = ["swlc_bucket", "swlc_gather", "swlc_block_topk",
            "sharded_swlc_matmat", "default_mesh"]
 
 
@@ -54,15 +53,6 @@ def auto_c_chunk(n_local: int, T: int, C: int,
     if n_local * T * C <= budget_elems:
         return None
     return max(1, min(C, budget_elems // max(n_local * T, 1)))
-
-
-@functools.partial(jax.jit, static_argnames=("total_leaves",))
-def swlc_matvec(gl: jax.Array, q: jax.Array, w: jax.Array, v: jax.Array,
-                total_leaves: int) -> jax.Array:
-    """(P v)[i] for P = SWLC(q, w);  gl/q/w: (N, T), v: (N,)."""
-    s = jax.ops.segment_sum((w * v[:, None]).ravel(), gl.ravel(),
-                            num_segments=total_leaves)
-    return (q * s[gl]).sum(axis=1)
 
 
 def _pad_trees(gl: jax.Array, x: jax.Array, total_leaves: int,
@@ -152,62 +142,6 @@ def _swlc_product(gl_q: jax.Array, q: jax.Array, gl_w: jax.Array,
                                             t_chunk), t_chunk)
 
 
-def swlc_matmat(gl: jax.Array, q: jax.Array, w: jax.Array, V: jax.Array,
-                total_leaves: int,
-                t_chunk: Optional[int] = None) -> jax.Array:
-    """(P V) for V: (N, C)  — the proximity-weighted prediction primitive.
-
-    Pass ``t_chunk`` to cap the dense (N, t_chunk, C) intermediate when C
-    is large.
-    """
-    return _swlc_product(gl, q, gl, w, V, total_leaves, t_chunk)
-
-
-@functools.partial(jax.jit, static_argnames=("t_chunk",))
-def swlc_block(gl_q: jax.Array, q: jax.Array, gl_w: jax.Array,
-               w: jax.Array, t_chunk: int = 8) -> jax.Array:
-    """Dense proximity block: P[i,j] = Σ_t q[i,t] w[j,t] 1[gl_q[i,t]=gl_w[j,t]].
-
-    Accumulates over tree chunks (like the Pallas block kernel) so the
-    intermediate is (B_q, B_w, t_chunk) instead of (B_q, B_w, T) —
-    B_q·B_r·T work at bounded memory.
-    """
-    nq, T = gl_q.shape
-    pad = (-T) % t_chunk
-    if pad:
-        # collision-free sentinel trees: -1 never equals -2
-        gl_q = jnp.pad(gl_q, ((0, 0), (0, pad)), constant_values=-1)
-        gl_w = jnp.pad(gl_w, ((0, 0), (0, pad)), constant_values=-2)
-        q = jnp.pad(q, ((0, 0), (0, pad)))
-        w = jnp.pad(w, ((0, 0), (0, pad)))
-
-    def body(c, acc):
-        s = c * t_chunk
-        gq = jax.lax.dynamic_slice_in_dim(gl_q, s, t_chunk, axis=1)
-        gw = jax.lax.dynamic_slice_in_dim(gl_w, s, t_chunk, axis=1)
-        qq = jax.lax.dynamic_slice_in_dim(q, s, t_chunk, axis=1)
-        ww = jax.lax.dynamic_slice_in_dim(w, s, t_chunk, axis=1)
-        coll = gq[:, None, :] == gw[None, :, :]
-        contrib = jnp.where(coll, qq[:, None, :] * ww[None, :, :], 0)
-        return acc + contrib.sum(axis=-1)
-
-    acc0 = jnp.zeros((nq, gl_w.shape[0]), dtype=q.dtype)
-    return jax.lax.fori_loop(0, (T + pad) // t_chunk, body, acc0)
-
-
-@functools.partial(jax.jit, static_argnames=("k",))
-def swlc_topk(gl_q: jax.Array, q: jax.Array, gl_w: jax.Array, w: jax.Array,
-              k: int) -> Tuple[jax.Array, jax.Array]:
-    """Top-k proximities of each query row against the reference set.
-
-    Materializes only the (B_q, N_w) block for the given query rows and
-    reduces it with ``lax.top_k`` on device — the streaming building block
-    of the engine's jax/pallas ``topk``.  Returns (values, indices).
-    """
-    B = swlc_block(gl_q, q, gl_w, w)
-    return jax.lax.top_k(B, k)
-
-
 @functools.partial(jax.jit, static_argnames=("k", "n_ref", "interpret"))
 def swlc_block_topk(gl_q: jax.Array, q: jax.Array, glwt: jax.Array,
                     wt: jax.Array, k: int, n_ref: int,
@@ -230,12 +164,6 @@ def swlc_block_topk(gl_q: jax.Array, q: jax.Array, glwt: jax.Array,
         B = block_prox_padded(glq, qq, glwt, wt, interpret=interpret)
     with jax.named_scope("select"):
         return jax.lax.top_k(B[:gl_q.shape[0], :n_ref], k)
-
-
-def swlc_predict(gl_q, q, gl_w, w, Y, total_leaves: int,
-                 t_chunk: Optional[int] = None) -> jax.Array:
-    """OOS proximity prediction: rows = queries, refs = (gl_w, w, Y)."""
-    return _swlc_product(gl_q, q, gl_w, w, Y, total_leaves, t_chunk)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "total_leaves",

@@ -1,10 +1,10 @@
 """Continuous-batching out-of-sample proximity serving.
 
 ``ProximityServer`` fronts a fitted :class:`~repro.core.engine.ProximityEngine`
-(full, prototype-compressed, or depth-prefix) with the slot design of
-:class:`~repro.serve.engine.ServingEngine`: a fixed pool of ``n_slots`` query
-slots, requests admitted into free slots as they arrive, and **one routed
-batch per tick** shared by every operation kind.
+(full, prototype-compressed, or depth-prefix) with continuous-batching
+slots: a fixed pool of ``n_slots`` query slots, requests admitted into free
+slots as they arrive (no waiting for a full batch), and **one routed batch
+per tick** shared by every operation kind.
 
 Request kinds and the engine op each maps to:
 
@@ -67,9 +67,8 @@ watermark passes new work down-ladder instead of queuing it toward a
 shed).  All of it is visible in ``stats()``.
 
 The slot buffer is host-owned and mutated on admission; engine calls get a
-defensive copy (`PR-1 async buffer-aliasing race
-<../serve/engine.py>`: zero-copy ``jnp.asarray`` of a mutated numpy buffer
-corrupts in-flight batches on CPU jax).
+defensive copy: a zero-copy ``jnp.asarray`` of a numpy buffer that is
+mutated while a batch is in flight corrupts that batch on CPU jax.
 """
 from __future__ import annotations
 
@@ -382,8 +381,8 @@ class ProximityServer(_MetricsHTTPMixin):
         self._g_occ.set(occ)
 
         # one routed batch per tick, in slot order; a defensive copy so no
-        # engine/backend ever aliases the mutable slot buffer (the PR-1
-        # async aliasing race pattern)
+        # engine/backend ever aliases the mutable slot buffer (see the
+        # module docstring)
         rows = np.sort(np.concatenate(
             [r.slots for r in self.active.values()]))
         X_tick = self._slot_X[rows].copy()

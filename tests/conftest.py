@@ -35,40 +35,63 @@ def rf_kernel_cache():
     return out
 
 
-@pytest.fixture(scope="session")
-def app_kernel_cache():
-    """Small (≤200-sample) kernels on all three engine backends sharing one
-    forest, plus the explicit dense oracle P = Q Wᵀ — the fixture for the
-    engine-primitive and proximity-application tests.
+def _engine_backends():
+    from repro.forest import _native
+    return ["scipy", "pallas"] + (["native"] if _native.available() else [])
 
-    'sym' is an additional symmetric-method (original) kernel on the same
-    forest for the spectral/embedding tests, with its own oracle 'P_sym'.
+
+@pytest.fixture(scope="session")
+def method_kernel_cache():
+    """Per weight assignment (``core.weights.ASSIGNMENTS``): one small
+    (180-sample) ForestKernel per engine backend, all on one shared
+    forest, plus the explicit dense oracle P = Q Wᵀ from the scipy factors
+    and the data it was fitted on.
+
+    The five random-forest assignments share one 12-tree forest fitted
+    once; ``boosted`` gets a 12-tree depth-4 gradient-boosted forest on
+    two-class data (boosted classification is binary).
     """
     from repro.core.api import ForestKernel
-    from repro.forest import _native
-    X, y = gaussian_classes(180, d=8, n_classes=3, sep=3.0, seed=5)
-    backends = ["scipy", "jax", "pallas"]
-    if _native.available():
-        backends.append("native")
+    from repro.core.weights import ASSIGNMENTS
+    rf_data = gaussian_classes(180, d=8, n_classes=3, sep=3.0, seed=5)
+    gbt_data = gaussian_classes(180, d=8, n_classes=2, sep=3.0, seed=5)
+    forests = {}          # by whether the forest is boosted
     out = {}
-    shared = None
-    for be in backends:
-        fk = ForestKernel(kernel_method="gap", n_trees=12, seed=0,
-                          engine_backend=be)
-        if shared is None:
-            fk.fit(X, y)
-            shared = fk.forest
-        else:
-            fk.forest = shared
-            fk.build_kernel_cache()
-        out[be] = fk
-    sym = ForestKernel(kernel_method="original", n_trees=12, seed=0)
-    sym.forest = shared
-    sym.build_kernel_cache()
-    out["sym"] = sym
-    out["P"] = np.asarray((out["scipy"].Q_ @ out["scipy"].W_.T).todense())
-    out["P_sym"] = np.asarray((sym.Q_ @ sym.W_.T).todense())
-    out["_data"] = (X, y)
+    for method in ASSIGNMENTS:
+        boosted = method == "boosted"
+        kw = dict(model_type="gbt", max_depth=4, max_features=None) \
+            if boosted else {}
+        data = gbt_data if boosted else rf_data
+        cache = {}
+        for be in _engine_backends():
+            fk = ForestKernel(kernel_method=method, n_trees=12, seed=0,
+                              engine_backend=be, **kw)
+            if boosted in forests:
+                fk.forest = forests[boosted]
+                fk.build_kernel_cache()
+            else:
+                forests[boosted] = fk.fit(*data).forest
+            cache[be] = fk
+        cache["P"] = np.asarray(
+            (cache["scipy"].Q_ @ cache["scipy"].W_.T).todense())
+        cache["_data"] = data
+        out[method] = cache
+    return out
+
+
+@pytest.fixture(scope="session")
+def app_kernel_cache(method_kernel_cache):
+    """The ``gap`` kernels of ``method_kernel_cache`` on every engine
+    backend and their dense oracle 'P' — the fixture for the
+    engine-primitive and proximity-application tests.
+
+    'sym' is the symmetric-method (original) scipy kernel on the same
+    forest for the spectral/embedding tests, with its own oracle 'P_sym'.
+    """
+    gap, sym = method_kernel_cache["gap"], method_kernel_cache["original"]
+    out = {be: gap[be] for be in _engine_backends()}
+    out.update(sym=sym["scipy"], P=gap["P"], P_sym=sym["P"],
+               _data=gap["_data"])
     return out
 
 

@@ -1,5 +1,5 @@
 """Proximity applications vs explicit dense oracles (P = Q Wᵀ, ≤200 samples),
-scipy/jax backend agreement, determinism, and the no-dense-P guard.
+scipy/pallas backend agreement, determinism, and the no-dense-P guard.
 """
 import numpy as np
 import pytest
@@ -11,7 +11,7 @@ from repro.applications.propagate import propagate_labels
 from repro.applications.prototypes import (NearestPrototypeClassifier,
                                            select_prototypes)
 
-BACKENDS = ["scipy", "jax", "pallas"]
+BACKENDS = ["scipy", "pallas"]
 
 
 # ------------------------------------------------------------------ outliers
@@ -130,7 +130,7 @@ def test_forestkernel_impute_surface(app_kernel_cache):
 def test_prototypes_class_membership_and_agreement(app_kernel_cache):
     _, y = app_kernel_cache["_data"]
     ref = None
-    for be in ["scipy", "jax"]:
+    for be in ["scipy", "pallas"]:
         protos, cov = select_prototypes(app_kernel_cache[be].engine, y,
                                         n_prototypes=3, k=40)
         for c, ids in protos.items():
@@ -262,10 +262,9 @@ def test_embed_asymmetric_operator_path(app_kernel_cache):
     assert (np.diff(emb.eigvals_) <= 1e-12).all()
     X, _ = app_kernel_cache["_data"]
     Z_ref = emb.transform(X[:20] + 1e-3)
-    for be in ["jax", "pallas"]:
-        emb.engine_ = app_kernel_cache[be].engine
-        np.testing.assert_allclose(emb.transform(X[:20] + 1e-3), Z_ref,
-                                   atol=1e-8)
+    emb.engine_ = app_kernel_cache["pallas"].engine
+    np.testing.assert_allclose(emb.transform(X[:20] + 1e-3), Z_ref,
+                               atol=1e-8)
 
 
 def test_embed_leafpca_path(app_kernel_cache):
@@ -300,7 +299,7 @@ BLOCK = 64
 def test_applications_never_densify_P(app_kernel_cache, monkeypatch):
     """Acceptance guard: run every workload with full_kernel forbidden and
     all dense-block/matmat shapes instrumented — P is never materialized
-    beyond a ≤BLOCK-row streaming chunk, on scipy and jax backends."""
+    beyond a ≤BLOCK-row streaming chunk, on scipy and pallas backends."""
     from repro.core import factorization
     from repro.core.engine import ProximityEngine
 
@@ -330,7 +329,7 @@ def test_applications_never_densify_P(app_kernel_cache, monkeypatch):
     monkeypatch.setattr(ProximityEngine, "kernel_block", spy_block)
     monkeypatch.setattr(ProximityEngine, "matmat", spy_matmat)
 
-    for be in ["scipy", "jax"]:
+    for be in ["scipy", "pallas"]:
         eng = app_kernel_cache[be].engine
         outlier_scores(eng, y, block=BLOCK)
         propagate_labels(eng, y, y >= 0, n_iter=5)

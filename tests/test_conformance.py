@@ -1,11 +1,13 @@
-"""Backend × op × side conformance matrix for the ProximityEngine.
+"""Backend × weight assignment × op × side conformance matrix for the
+ProximityEngine.
 
-Every backend {scipy, jax, pallas, native} must agree with a **dense numpy
+Every backend {scipy, pallas, native} must agree with a **dense numpy
 oracle** (P materialized from the CSR factors) to atol 1e-8 on every engine
 op {matvec, matmat, predict, topk, row_sums, squared_row_sums, kernel_block}
-for both training-set and out-of-sample query batches.  This is the
-acceptance gate for any new backend or op: one parametrized matrix, no
-backend-specific carve-outs.
+under each of the six weight assignments {original, kerf, oob, gap, ih,
+boosted}, for both training-set and out-of-sample query batches.  This is
+the acceptance gate for any new backend, op or assignment: one parametrized
+matrix, no backend-specific carve-outs.
 
 Property tests (``_hyp`` shim: hypothesis when installed, deterministic
 fallback otherwise) push the same agreement through degenerate forests —
@@ -18,7 +20,7 @@ from repro.core.api import ForestKernel
 from repro.core.context import EnsembleContext
 from repro.core.engine import (ENGINE_BACKENDS, PrefixProximityEngine,
                                ProximityEngine)
-from repro.core.weights import get_assignment
+from repro.core.weights import ASSIGNMENTS, get_assignment
 from repro.data.synthetic import gaussian_classes
 from repro.forest import _native
 
@@ -113,11 +115,14 @@ OPS = {
 
 @pytest.mark.parametrize("side", SIDES)
 @pytest.mark.parametrize("op", sorted(OPS))
+@pytest.mark.parametrize("kernel_method", tuple(ASSIGNMENTS))
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_conformance_matrix(app_kernel_cache, backend, op, side):
-    eng = app_kernel_cache[backend].engine
-    _, y = app_kernel_cache["_data"]
-    P, X = _oracle(app_kernel_cache, side)
+def test_conformance_matrix(method_kernel_cache, backend, kernel_method, op,
+                            side):
+    cache = method_kernel_cache[kernel_method]
+    eng = cache[backend].engine
+    _, y = cache["_data"]
+    P, X = _oracle(cache, side)
     OPS[op](eng, P, y, X)
 
 

@@ -1,4 +1,4 @@
-"""ProximityEngine backend equivalence: scipy vs jax vs pallas.
+"""ProximityEngine backend equivalence: scipy vs pallas vs native.
 
 The acceptance bar: predict / topk / kernel_block / matvec must agree with
 the scipy CSR reference path to atol 1e-8 on every backend, with no per-tree
@@ -105,7 +105,7 @@ def test_matvec_matmat_identical_across_backends(rf_kernel_cache):
     for be in NON_SCIPY:
         np.testing.assert_allclose(engines[be].matvec(v), ref_v, atol=1e-8)
         np.testing.assert_allclose(engines[be].matmat(V), ref_V, atol=1e-8)
-    op = engines["jax"].operator()
+    op = engines["pallas"].operator()
     np.testing.assert_allclose(op @ v, ref_v, atol=1e-8)
 
 
@@ -149,7 +149,7 @@ def test_block_dtype_float32_only_for_compiled_pallas(rf_kernel_cache,
     assert build("pallas").block_dtype == np.float64      # interpret mode
     monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
     assert build("pallas").block_dtype == np.float32
-    assert build("jax").block_dtype == np.float64
+    assert build("scipy").block_dtype == np.float64
 
 
 def test_op_paths_count_the_host_cutover(rf_kernel_cache, monkeypatch):
@@ -158,10 +158,10 @@ def test_op_paths_count_the_host_cutover(rf_kernel_cache, monkeypatch):
     from repro.obs.metrics import global_registry
     fk = rf_kernel_cache["gap"]
     eng = ProximityEngine(fk.ctx, fk.assignment, forest=fk.forest,
-                          backend="jax")
+                          backend="pallas")
     fam = global_registry().counter(
         "engine_op_path_total", labels=("op", "backend", "path"))
-    count = lambda op, path: fam.labels(op=op, backend="jax",
+    count = lambda op, path: fam.labels(op=op, backend="pallas",
                                         path=path).value
     before = {op: (count(op, "device"), count(op, "host_cutover"))
               for op in ("topk", "squared_row_sums")}
@@ -263,7 +263,7 @@ def test_sharded_matmat_single_device_fallback(app_kernel_cache):
     if jax.device_count() > 1:
         pytest.skip("requires a single-device jax runtime")
     assert default_mesh() is None
-    eng = app_kernel_cache["jax"].engine
+    eng = app_kernel_cache["pallas"].engine
     rng = np.random.default_rng(2)
     V = rng.normal(size=(eng.W.shape[0], 3))
     ref = app_kernel_cache["scipy"].engine.matmat(V)
@@ -271,20 +271,29 @@ def test_sharded_matmat_single_device_fallback(app_kernel_cache):
     assert eng.last_matmat_path == "segment"
 
 
-@pytest.mark.slow
-def test_engine_sharded_matmat_multi_device():
-    """Forced 8-host-device subprocess: the train-state jax matmat routes
-    through sharded_swlc_matmat and agrees with scipy; OOS batches fall back
-    to the segment path."""
+def _run_forced_devices(code: str, devices: int = 8) -> str:
+    """stdout of ``code`` in a subprocess with ``devices`` forced host
+    devices (this process keeps its single device)."""
     import os
     import subprocess
     import sys
     import textwrap
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH=os.path.join(repo, "src"))
-    code = textwrap.dedent("""
+    r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                       capture_output=True, text=True, env=env, timeout=600)
+    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-3000:]}"
+    return r.stdout
+
+
+@pytest.mark.slow
+def test_engine_sharded_matmat_multi_device():
+    """Forced 8-host-device subprocess: the train-state pallas matmat routes
+    through sharded_swlc_matmat and agrees with scipy; OOS batches fall back
+    to the segment path."""
+    out = _run_forced_devices("""
         import numpy as np
         from repro.core.api import ForestKernel
         from repro.data.synthetic import gaussian_classes
@@ -292,7 +301,7 @@ def test_engine_sharded_matmat_multi_device():
         # pads its rows
         X, y = gaussian_classes(162, d=8, n_classes=3, seed=5)
         fk = ForestKernel(kernel_method="gap", n_trees=10, seed=0,
-                          engine_backend="jax").fit(X, y)
+                          engine_backend="pallas").fit(X, y)
         ref = ForestKernel(kernel_method="gap", n_trees=10, seed=0)
         ref.forest = fk.forest
         ref.build_kernel_cache()
@@ -316,10 +325,28 @@ def test_engine_sharded_matmat_multi_device():
         jax_ops.auto_c_chunk = orig
         print("SHARDED ENGINE OK")
     """)
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, env=env, timeout=600)
-    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-3000:]}"
-    assert "SHARDED ENGINE OK" in r.stdout
+    assert "SHARDED ENGINE OK" in out
+
+
+@pytest.mark.slow
+def test_sharded_swlc_matmat():
+    out = _run_forced_devices("""
+        import numpy as np, jax, jax.numpy as jnp
+        from jax.sharding import Mesh
+        from repro.core.jax_ops import sharded_swlc_matmat
+        from repro.core.factorization import naive_swlc
+        mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
+        rng = np.random.default_rng(0)
+        N, T, L = 64, 8, 40
+        gl = rng.integers(0, 5, (N, T)) + np.arange(T)[None] * 5
+        q = rng.random((N, T)); w = rng.random((N, T)); V = rng.random((N, 3))
+        P = naive_swlc(gl, gl, q, w)
+        out = sharded_swlc_matmat(mesh, jnp.array(gl), jnp.array(q),
+                                  jnp.array(w), jnp.array(V), L)
+        np.testing.assert_allclose(P @ V, np.asarray(out), rtol=1e-4, atol=1e-4)
+        print("SWLC SHARDED OK")
+    """)
+    assert "SWLC SHARDED OK" in out
 
 
 # ------------------------------------------ reference bucket tables (cache) --
@@ -349,7 +376,7 @@ def _count_buckets(monkeypatch):
 
 
 def _fused_predict(eng, y, C, X=None):
-    """The single fused program of both stages (``swlc_predict``), with
+    """The single fused program of both stages (``_swlc_product``), with
     the engine's own inputs and the self-term removed for X=None."""
     import jax
     import jax.numpy as jnp
@@ -357,7 +384,7 @@ def _fused_predict(eng, y, C, X=None):
     qs = eng.query_state(X)
     Y, _ = eng._label_table(y, C)
     with jax.enable_x64(True):
-        out = np.asarray(jax_ops.swlc_predict(
+        out = np.asarray(jax_ops._swlc_product(
             *(jnp.asarray(a) for a in (qs.gl, qs.q, eng.gl, eng.w, Y)),
             eng.total_leaves, t_chunk=1))
     if X is None:
@@ -366,7 +393,7 @@ def _fused_predict(eng, y, C, X=None):
 
 
 @pytest.mark.parametrize("oos", [False, True])
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
+@pytest.mark.parametrize("backend", ["pallas"])
 def test_device_ref_table_predict_is_the_fused_product(rf_kernel_cache,
                                                        backend, oos):
     """With S built once and kept on the device, predict gives the fused
@@ -408,7 +435,7 @@ def test_second_predict_hits_the_ref_table(rf_kernel_cache, backend,
         {"hit": 1, "miss": 0, "uncached": 0}
     assert (eng.ref_cache_hits, eng.ref_cache_misses) == (1, 1)
     (_, S), = eng._ref_cache.values()
-    on_device = backend in ("jax", "pallas")
+    on_device = backend == "pallas"
     assert isinstance(S, jax.Array) == on_device
     assert buckets == ([(len(y), C)] if on_device else [])
     np.testing.assert_allclose(
@@ -419,7 +446,7 @@ def test_second_predict_hits_the_ref_table(rf_kernel_cache, backend,
 
 
 @pytest.mark.parametrize("how", ["wide", "budget_chunked"])
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
+@pytest.mark.parametrize("backend", ["pallas"])
 def test_uncached_ref_tables_stay_correct(rf_kernel_cache, backend, how,
                                           monkeypatch):
     """Wide V (33 columns) and the column chunks of a memory budget are
@@ -451,7 +478,7 @@ def test_byte_budget_evicts_device_ref_tables(rf_kernel_cache):
     X, y = rf_kernel_cache["_data"]
     C = fk.forest.n_classes_
     eng = ProximityEngine(fk.ctx, fk.assignment, forest=fk.forest,
-                          backend="jax")
+                          backend="pallas")
     table = (eng.total_leaves + 1) * C * 8
     eng._ref_cache_byte_budget = table + table // 2
     y2 = np.roll(y, 1)

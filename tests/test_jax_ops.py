@@ -9,15 +9,21 @@ import jax.numpy as jnp
 from _hyp import given, settings, st   # hypothesis, or deterministic fallback
 
 from repro.core.factorization import naive_swlc
-from repro.core.jax_ops import (_swlc_product, swlc_block, swlc_bucket,
-                                swlc_gather, swlc_matmat, swlc_matvec,
-                                swlc_predict)
+from repro.core.jax_ops import _swlc_product, swlc_bucket, swlc_gather
 from repro.core.spectral import LeafPCA, kernel_eigs
+from repro.kernels.block_prox.ref import block_prox_ref
 
 
 def _leafset(rng, n, T, lpt):
     gl = rng.integers(0, lpt, (n, T)) + np.arange(T)[None, :] * lpt
     return gl.astype(np.int32)
+
+
+def _train_product(gl, q, w, V, total_leaves, t_chunk=None):
+    """P V over the training set (queries = references) as one program."""
+    gl = jnp.asarray(gl)
+    return np.asarray(_swlc_product(gl, jnp.asarray(q), gl, jnp.asarray(w),
+                                    jnp.asarray(V), total_leaves, t_chunk))
 
 
 @settings(max_examples=25, deadline=None)
@@ -30,10 +36,9 @@ def test_swlc_matvec_property(n, T, lpt, seed):
     w = rng.random((n, T))
     v = rng.random(n)
     P = naive_swlc(gl, gl, q, w)
-    got = swlc_matvec(jnp.asarray(gl), jnp.asarray(q, jnp.float32),
-                      jnp.asarray(w, jnp.float32), jnp.asarray(v, jnp.float32),
-                      T * lpt)
-    np.testing.assert_allclose(np.asarray(got), P @ v, rtol=2e-4, atol=2e-4)
+    f32 = lambda a: a.astype(np.float32)
+    got = _train_product(gl, f32(q), f32(w), f32(v)[:, None], T * lpt)[:, 0]
+    np.testing.assert_allclose(got, P @ v, rtol=2e-4, atol=2e-4)
 
 
 def test_swlc_matmat_and_block():
@@ -44,11 +49,10 @@ def test_swlc_matmat_and_block():
     w = rng.random((n, T)).astype(np.float32)
     V = rng.random((n, 4)).astype(np.float32)
     P = naive_swlc(gl, gl, q, w)
-    got = swlc_matmat(jnp.asarray(gl), jnp.asarray(q), jnp.asarray(w),
-                      jnp.asarray(V), T * lpt)
-    np.testing.assert_allclose(np.asarray(got), P @ V, rtol=2e-4, atol=2e-4)
-    B = swlc_block(jnp.asarray(gl[:16]), jnp.asarray(q[:16]),
-                   jnp.asarray(gl), jnp.asarray(w))
+    got = _train_product(gl, q, w, V, T * lpt)
+    np.testing.assert_allclose(got, P @ V, rtol=2e-4, atol=2e-4)
+    B = block_prox_ref(jnp.asarray(gl[:16]), jnp.asarray(q[:16]),
+                       jnp.asarray(gl), jnp.asarray(w))
     np.testing.assert_allclose(np.asarray(B), P[:16], rtol=2e-4, atol=2e-4)
 
 
@@ -60,13 +64,10 @@ def test_swlc_matmat_tree_chunked_matches_unchunked():
     q = rng.random((n, T)).astype(np.float32)
     w = rng.random((n, T)).astype(np.float32)
     V = rng.random((n, 3)).astype(np.float32)
-    ref = np.asarray(swlc_matmat(jnp.asarray(gl), jnp.asarray(q),
-                                 jnp.asarray(w), jnp.asarray(V), T * lpt))
+    ref = _train_product(gl, q, w, V, T * lpt)
     for tc in (1, 2, 3, 7, 16):
-        got = swlc_matmat(jnp.asarray(gl), jnp.asarray(q), jnp.asarray(w),
-                          jnp.asarray(V), T * lpt, t_chunk=tc)
-        np.testing.assert_allclose(np.asarray(got), ref, rtol=1e-5,
-                                   atol=1e-5)
+        got = _train_product(gl, q, w, V, T * lpt, t_chunk=tc)
+        np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
 
 
 def test_swlc_matmat_large_C_chunked_regression():
@@ -82,9 +83,8 @@ def test_swlc_matmat_large_C_chunked_regression():
     w = rng.random((n, T)).astype(np.float32)
     V = rng.random((n, C)).astype(np.float32)
     P = naive_swlc(gl, gl, q, w)
-    got = swlc_matmat(jnp.asarray(gl), jnp.asarray(q), jnp.asarray(w),
-                      jnp.asarray(V), T * lpt, t_chunk=tc)
-    np.testing.assert_allclose(np.asarray(got), P @ V, rtol=2e-3, atol=2e-3)
+    got = _train_product(gl, q, w, V, T * lpt, t_chunk=tc)
+    np.testing.assert_allclose(got, P @ V, rtol=2e-3, atol=2e-3)
 
 
 def test_swlc_predict_oos():
@@ -96,8 +96,9 @@ def test_swlc_predict_oos():
     w = rng.random((n, T)).astype(np.float32)
     Y = rng.random((n, 3)).astype(np.float32)
     P = naive_swlc(gl_q, gl_w, q, w)
-    got = swlc_predict(jnp.asarray(gl_q), jnp.asarray(q), jnp.asarray(gl_w),
-                       jnp.asarray(w), jnp.asarray(Y), T * lpt)
+    S = swlc_bucket(jnp.asarray(gl_w), jnp.asarray(w), jnp.asarray(Y),
+                    T * lpt)
+    got = swlc_gather(jnp.asarray(gl_q), jnp.asarray(q), S)
     np.testing.assert_allclose(np.asarray(got), P @ Y, rtol=2e-4, atol=2e-4)
 
 

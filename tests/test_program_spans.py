@@ -71,7 +71,7 @@ def test_span_carries_stats(tmp_path):
     assert ev[0][3]["bytes"] == 123 and ev[0][3]["kind"] == "a"
 
 
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
+@pytest.mark.parametrize("backend", ["pallas"])
 def test_predict_spans_once_per_call_in_order(fitted, tmp_path, backend):
     fk = _kernel(fitted, backend)
     eng, (_, y, batches) = fk.engine, fitted
@@ -105,7 +105,7 @@ def test_predict_spans_once_per_call_in_order(fitted, tmp_path, backend):
 
 
 def test_cache_hit_skips_routing_spans(fitted, tmp_path):
-    fk = _kernel(fitted, "jax")
+    fk = _kernel(fitted, "pallas")
     eng, (_, y, batches) = fk.engine, fitted
     eng.predict(y, n_classes=3, X=batches[0])
     ev = _program_events(tmp_path, lambda: eng.predict(y, n_classes=3,
@@ -113,20 +113,15 @@ def test_cache_hit_skips_routing_spans(fitted, tmp_path):
     assert [e[0] for e in ev] == ["engine.batch_key"] + DEVICE
 
 
-@pytest.mark.parametrize("backend,op", [("jax", "kernel_block"),
-                                        ("jax", "topk"),
-                                        ("pallas", "kernel_block")])
-def test_block_op_upload_bytes(fitted, tmp_path, backend, op, monkeypatch):
-    """Every staging of a dense block op is under an ``engine.upload`` span
-    whose bytes are those staged; the jax path stages in row chunks.  The
-    pallas wrapper stages its own inputs: its ``engine.dispatch`` span
+@pytest.mark.parametrize("backend,op", [("pallas", "kernel_block")])
+def test_block_op_upload_bytes(fitted, tmp_path, backend, op):
+    """The pallas ``block_prox`` wrapper of ``kernel_block`` stages its own
+    inputs: no ``engine.upload`` span, and its ``engine.dispatch`` span
     carries the bytes of the host arrays it is handed."""
     fk = _kernel(fitted, backend)
     eng, (_, _, batches) = fk.engine, fitted
     Xq = batches[1]
-    monkeypatch.setattr(type(eng), "_op_row_chunk", lambda self, n: 16)
-    run = (lambda: eng.kernel_block(X_rows=Xq)) if op == "kernel_block" \
-        else (lambda: eng.topk(k=5, X=Xq))
+    run = lambda: eng.kernel_block(X_rows=Xq)
     want = run()
     ev = _program_events(tmp_path, run)
     np.testing.assert_array_equal(run(), want)
@@ -136,13 +131,8 @@ def test_block_op_upload_bytes(fitted, tmp_path, backend, op, monkeypatch):
     dispatch = [e[3] for e in ev if e[0] == "engine.dispatch"]
     fetches = sum(e[0] == "engine.fetch" for e in ev)
     assert eng.gl.dtype == np.int64 and eng.w.dtype == np.float64
-    if backend == "pallas":
-        assert ups == [] and fetches == 1
-        assert dispatch == [{"bytes": (nq + n) * T * 16}]
-    else:
-        # reference once, then 16-row query chunks: gl int64, q float64
-        assert ups == [2 * n * T * 8, 16 * T * 16, 16 * T * 16, 8 * T * 16]
-        assert dispatch == [{}] * 3 and fetches == 3
+    assert ups == [] and fetches == 1
+    assert dispatch == [{"bytes": (nq + n) * T * 16}]
 
 
 def test_pallas_topk_stages_the_reference_once(fitted, tmp_path):
